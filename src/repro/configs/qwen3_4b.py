@@ -1,4 +1,5 @@
-"""Qwen3-4B [hf:Qwen/Qwen3-8B family]: dense, GQA kv=8, qk_norm."""
+"""Qwen3-4B [hf:Qwen/Qwen3-4B, config.json]: dense, GQA kv=8, qk_norm,
+tied input/output embeddings (``tie_word_embeddings: true``)."""
 from repro.models.config import ModelConfig
 
 CONFIG = ModelConfig(
@@ -14,4 +15,5 @@ CONFIG = ModelConfig(
     qk_norm=True,
     rope_theta=1_000_000.0,
     mlp_activation="swiglu",
+    tie_embeddings=True,
 )

@@ -29,10 +29,10 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
                   page_size: int, pages_per_seq: int, softcap,
                   quantized: bool = False):
     if quantized:
-        # int8 KV pages: dequantize in-kernel from the per-(slot, kv-head)
-        # fp32 scales riding in as two extra page-indexed operands (the
-        # MaxText AQT kv_quant idiom — codes and scales DMA together from
-        # the same physical page the block table points at).
+        # int8 KV pages: the per-(slot, kv-head) fp32 scales ride in as two
+        # extra page-indexed operands (the MaxText AQT kv_quant idiom —
+        # codes and scales DMA together from the same physical page the
+        # block table points at).
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
         o_ref, m_scr, l_scr, acc_scr = rest
@@ -48,15 +48,18 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
     q = q_ref[0, 0].astype(jnp.float32)          # (G, d)
     k = k_ref[0, 0].astype(jnp.float32)          # (page_size, d)
     v = v_ref[0, 0].astype(jnp.float32)
-    if quantized:
-        k = k * ks_ref[0, 0][:, None]            # (page_size,) scales
-        v = v * vs_ref[0, 0][:, None]
     d = q.shape[-1]
     length = len_ref[bi]
     assigned = bt_ref[bi * pages_per_seq + pj] >= 0
 
     logits = jax.lax.dot_general(q * (d ** -0.5), k,
                                  (((1,), (1,)), ((), ())))  # (G, page_size)
+    if quantized:
+        # a slot's scale factors out of its dot products, so the scales
+        # apply lane-wise to the (G, page_size) logits and probabilities
+        # instead of to the (page_size, d) tiles: no relayout of the
+        # (1, page_size) scale row is needed.
+        logits = logits * ks_ref[0, 0]           # (1, page_size) scales
     if softcap is not None:
         logits = softcap * jnp.tanh(logits / softcap)
     pos = pj * page_size + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
@@ -68,7 +71,8 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
     p = jnp.exp(logits - m_new)
     alpha = jnp.exp(m_prev - m_new)
     l_scr[...] = l_scr[...] * alpha + p.sum(axis=-1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot(p.astype(v.dtype), v)
+    pv = p * vs_ref[0, 0] if quantized else p
+    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot(pv.astype(v.dtype), v)
     m_scr[...] = m_new
 
     @pl.when(pj == pages_per_seq - 1)
@@ -86,8 +90,8 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
     lengths: (B,) int32 tokens written so far.  Returns (B, H, D).
 
     ``k_scales``/``v_scales`` (both or neither): (N, page_size, KV) fp32
-    per-(slot, kv-head) scales for int8 pages — the kernel dequantizes
-    each page tile in VMEM right after the DMA (``kv_quant="int8"``)."""
+    per-(slot, kv-head) scales for int8 pages — the kernel applies them in
+    VMEM to each page's logits and probabilities (``kv_quant="int8"``)."""
     b, h, d = q.shape
     n, page_size, kv = k_pages.shape[0], k_pages.shape[1], k_pages.shape[2]
     p_seq = block_tables.shape[1]
@@ -112,7 +116,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
     def scale_map(bb, hh, pj, bt, ln):
         del ln
         idx = jnp.maximum(bt[bb * p_seq + pj], 0)
-        return (idx, hh, 0)
+        return (idx, hh, 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, 1, g_pad, d), lambda bb, hh, pj, bt, ln: (bb, hh, 0, 0)),
@@ -121,12 +125,16 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
     ]
     operands = [qg, kt, vt]
     if quantized:
-        # (N, page, KV) -> (N, KV, page): same physical-page indexing as
-        # the code tiles, one (1, 1, page_size) fp32 block per grid step.
-        in_specs += [pl.BlockSpec((1, 1, page_size), scale_map),
-                     pl.BlockSpec((1, 1, page_size), scale_map)]
-        operands += [k_scales.transpose(0, 2, 1).astype(jnp.float32),
-                     v_scales.transpose(0, 2, 1).astype(jnp.float32)]
+        # (N, page, KV) -> (N, KV, 1, page): same physical-page indexing as
+        # the code tiles.  The unit axis makes each grid step's block a
+        # (1, page_size) row whose last two dims equal the array's — the
+        # TPU's tiling rule refuses a (1, 1, page_size) block over a
+        # 3-D (N, KV, page) array.
+        def scale_rows(s):
+            return s.transpose(0, 2, 1)[:, :, None, :].astype(jnp.float32)
+        in_specs += [pl.BlockSpec((1, 1, 1, page_size), scale_map),
+                     pl.BlockSpec((1, 1, 1, page_size), scale_map)]
+        operands += [scale_rows(k_scales), scale_rows(v_scales)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,

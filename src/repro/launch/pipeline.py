@@ -61,7 +61,10 @@ class PipelineSettings:
     page_size: int = 16                    # paged engine: KV page tokens
     prefill_chunk: int = 16                # paged engine: prefill chunk tokens
     num_pages: Optional[int] = None        # paged engine: pool size (auto)
-    attn_impl: str = "ref"                 # ref | kernel | kernel_interpret
+    # paged decode attention: None follows the platform (the Pallas kernel
+    # on TPU, the gather reference elsewhere); ref | kernel |
+    # kernel_interpret pin one for parity tests.
+    attn_impl: Optional[str] = None
     # automatic cross-prompt prefix caching (radix tree over KV pages).
     # "auto"/"on": enabled on the paged engine; "off": disabled.  The slot
     # engine has no page pool — the setting passes through as a no-op there.
@@ -145,8 +148,11 @@ def make_slo_config(s: PipelineSettings) -> Optional[SLOConfig]:
         replica_stall_s=s.slo_replica_stall or None)
 
 
-def make_rollout_engine(api, params, s: PipelineSettings) -> RolloutEngine:
-    """Construct the rollout engine per ``s.rollout_engine`` (see above)."""
+def make_rollout_engine(api, params, s: PipelineSettings,
+                        device=None) -> RolloutEngine:
+    """Construct the rollout engine per ``s.rollout_engine`` (see above).
+    ``device`` pins a paged engine's state to one device (None: default
+    placement; the slot engine always takes the default)."""
     if s.prefix_cache not in ("auto", "on", "off"):
         raise ValueError(f"unknown prefix_cache {s.prefix_cache!r} "
                          "(expected auto | on | off)")
@@ -159,7 +165,7 @@ def make_rollout_engine(api, params, s: PipelineSettings) -> RolloutEngine:
             page_size=s.page_size, prefill_chunk=s.prefill_chunk,
             num_pages=s.num_pages, eos_id=EOS, seed=s.seed,
             attn_impl=s.attn_impl, prefix_cache=s.prefix_cache != "off",
-            quant_mode=s.rollout_quant, kv_quant=s.kv_quant)
+            quant_mode=s.rollout_quant, kv_quant=s.kv_quant, device=device)
     if choice != "slot":
         raise ValueError(f"unknown rollout_engine {s.rollout_engine!r} "
                          "(expected auto | paged | slot)")
@@ -186,12 +192,21 @@ def make_rollout_fleet(api, params, s: PipelineSettings,
     With ``autoscale_max_replicas`` armed the router also gets a
     ``replica_factory`` (same shard shape, fresh per-replica seed) so
     ``add_replica``/scale-up can grow the fleet mid-run, plus the
-    hysteresis policy driving load-triggered elasticity."""
+    hysteresis policy driving load-triggered elasticity.
+
+    Replica ``i`` lives on ``jax.devices()[i % D]``: on a D-chip host each
+    replica gets its own chip, and weight syncs copy the trainer's tree to
+    it."""
     n = max(1, int(s.num_rollout_replicas))
     elastic = s.autoscale_max_replicas > n
     slo = make_slo_config(s)
+    devices = jax.devices()
+
+    def device(i: int):
+        return devices[i % len(devices)]
+
     if n == 1 and not elastic:
-        engine = make_rollout_engine(api, params, s)
+        engine = make_rollout_engine(api, params, s, device(0))
         # a lone proxy IS the front door: it keeps the full SLO config,
         # queue bounds included
         return [engine], [LLMProxy(engine, slo=slo)], None
@@ -204,7 +219,8 @@ def make_rollout_fleet(api, params, s: PipelineSettings,
     # per-replica sampler seeds: identical streams across replicas would
     # silently duplicate stochastic rollouts (greedy is seed-invariant)
     engines = [make_rollout_engine(api, params,
-                                   dataclasses.replace(shard, seed=s.seed + i))
+                                   dataclasses.replace(shard, seed=s.seed + i),
+                                   device(i))
                for i in range(n)]
     proxies = [LLMProxy(e, name=f"llm_proxy_{i}", slo=replica_slo)
                for i, e in enumerate(engines)]
@@ -213,7 +229,8 @@ def make_rollout_fleet(api, params, s: PipelineSettings,
     def factory() -> LLMProxy:
         i = next(counter)
         e = make_rollout_engine(api, params,
-                                dataclasses.replace(shard, seed=s.seed + i))
+                                dataclasses.replace(shard, seed=s.seed + i),
+                                device(i))
         return LLMProxy(e, name=f"llm_proxy_{i}", slo=replica_slo)
 
     policy = AutoscalePolicy(

@@ -20,6 +20,7 @@ import time
 
 from repro.configs import REGISTRY
 from repro.data.dataset import VOCAB
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.pipeline import PipelineSettings, build_rlvr_pipeline
 
 PRESETS = {
@@ -108,6 +109,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default=None, help="write step stats JSON here")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = build_model_cfg(args.arch, args.preset)
     settings = PipelineSettings(

@@ -23,8 +23,9 @@ def dense_init(key, d_in: int, d_out: int, dtype, scale: float | None = None):
     return (jax.random.truncated_normal(key, -2.0, 2.0, (d_in, d_out), jnp.float32) * scale).astype(dtype)
 
 
-def embed_init(key, vocab: int, d_model: int, dtype):
-    return (jax.random.truncated_normal(key, -2.0, 2.0, (vocab, d_model), jnp.float32)).astype(dtype)
+def embed_init(key, vocab: int, d_model: int, dtype, scale: float = 1.0):
+    return (jax.random.truncated_normal(key, -2.0, 2.0, (vocab, d_model), jnp.float32)
+            * scale).astype(dtype)
 
 
 def rmsnorm_init(d: int, dtype):

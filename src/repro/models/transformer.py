@@ -76,8 +76,15 @@ def _hybrid_layout(cfg: ModelConfig):
 def init_lm(key, cfg: ModelConfig):
     dt = jnp.dtype(cfg.dtype)
     ks = jax.random.split(key, 4)
+    # A tied table is also the output head, so it starts at the head's
+    # scale, 1/sqrt(d_model) (dense_init's for lm_head; Qwen3's 0.02 at
+    # d_model 2560).  At unit scale the input token's own logit (~d_model)
+    # would dwarf every other: a random-weight policy would only repeat
+    # its input, with a saturated softmax and zero policy gradient.
+    embed_scale = cfg.d_model ** -0.5 if cfg.tie_embeddings else 1.0
     params: dict[str, Any] = {
-        "embed": module.embed_init(ks[0], cfg.vocab_size, cfg.d_model, dt),
+        "embed": module.embed_init(ks[0], cfg.vocab_size, cfg.d_model, dt,
+                                   scale=embed_scale),
         "final_norm": module.rmsnorm_init(cfg.d_model, cfg.dtype),
     }
     if not cfg.tie_embeddings:

@@ -55,19 +55,6 @@ class QuantLeaf(NamedTuple):
     dtype_token: jax.Array  # shape (), original dtype
 
 
-def _fp8_cast(x):
-    """Round fp32 onto the e4m3fn grid (and back to fp32)."""
-    if hasattr(jnp, "float8_e4m3fn"):
-        return x.astype(jnp.float8_e4m3fn)
-    # simulated grid: clamp to max normal, round mantissa to 3 bits at the
-    # value's binade (subnormals collapse toward 0 — same as the real cast
-    # for the magnitudes per-channel scaling produces).
-    mag = jnp.clip(jnp.abs(x), 0.0, _FP8_MAX)
-    exp = jnp.floor(jnp.log2(jnp.maximum(mag, 2.0 ** -9)))
-    ulp = jnp.exp2(exp - 3.0)
-    return jnp.sign(x) * jnp.round(mag / ulp) * ulp
-
-
 def _per_channel_scale(x, qmax: float):
     xf = x.astype(jnp.float32)
     axes = tuple(range(x.ndim - 1))
@@ -85,7 +72,7 @@ def quantize_array(x: jax.Array, mode: str) -> QuantLeaf:
         return QuantLeaf(codes.astype(jnp.int8), scale, token)
     if mode == "fp8":
         scale = _per_channel_scale(x, _FP8_MAX)
-        codes = _fp8_cast(xf / scale)
+        codes = (xf / scale).astype(jnp.float8_e4m3fn)
         return QuantLeaf(codes, scale, token)
     raise ValueError(f"unknown quant mode {mode!r} (expected int8 | fp8)")
 

@@ -105,12 +105,28 @@ class _Retained:
     epoch: int = 0                       # weight epoch the KV was computed under
 
 
+ATTN_IMPLS = ("ref", "kernel", "kernel_interpret")
+
+
+def platform_attn_impl(device=None) -> str:
+    """Decode attention for a platform: the Pallas kernel on TPU, the
+    pure-JAX gather reference elsewhere."""
+    platform = device.platform if device is not None else jax.default_backend()
+    return "kernel" if platform == "tpu" else "ref"
+
+
 class PagedDecodeEngine:
     """Continuous-batching engine over a refcounted paged KV pool.
 
-    ``attn_impl``: "ref" (pure-JAX gather, exact vs the slot engine),
-    "kernel" (Pallas paged decode attention) or "kernel_interpret"
-    (Pallas interpret mode, for CPU validation).
+    ``attn_impl``: None (default) follows the platform — see
+    ``platform_attn_impl``.  The explicit choices serve parity tests:
+    "ref" (pure-JAX gather, exact vs the slot engine), "kernel" (Pallas
+    paged decode attention) or "kernel_interpret" (Pallas interpret mode,
+    for CPU validation).
+
+    ``device``: commit the engine's weights, page pool and tables to this
+    device (each replica of a fleet on its own chip); weight syncs land
+    there too.  None keeps JAX's default placement.
     """
 
     supports_retain = True
@@ -120,9 +136,10 @@ class PagedDecodeEngine:
                  max_total_len: int = 128, page_size: int = 16,
                  prefill_chunk: int = 16, num_pages: Optional[int] = None,
                  eos_id: int = 2, temperature: float = 1.0, top_k: int = 0,
-                 pad_id: int = 0, seed: int = 0, attn_impl: str = "ref",
+                 pad_id: int = 0, seed: int = 0,
+                 attn_impl: Optional[str] = None,
                  prefix_cache: bool = False, quant_mode: str = "off",
-                 kv_quant: str = "off"):
+                 kv_quant: str = "off", device=None):
         cfg = api.cfg
         if api.init_paged_cache is None:
             raise ValueError(f"family {cfg.family} has no paged-KV support "
@@ -136,13 +153,19 @@ class PagedDecodeEngine:
         if kv_quant not in quant.KV_MODES:
             raise ValueError(f"unknown kv_quant {kv_quant!r} "
                              f"(expected {' | '.join(quant.KV_MODES)})")
+        if attn_impl is None:
+            attn_impl = platform_attn_impl(device)
+        if attn_impl not in ATTN_IMPLS:
+            raise ValueError(f"unknown attn_impl {attn_impl!r} "
+                             f"(expected {' | '.join(ATTN_IMPLS)})")
         self.api = api
+        self.device = device
         # quantize-on-sync: replicas hold int8/fp8 codes on device (the
         # trainer's tree is quantized HERE, at construction and on every
         # update_weights) and the jitted step dequantizes at trace time.
         self.quant_mode = quant_mode
         self.kv_quant = kv_quant
-        self.params = quant.quantize_params(params, quant_mode)
+        self.params = self._place(quant.quantize_params(params, quant_mode))
         self.total_weight_syncs_quantized = 0
         self.num_slots = num_slots
         self.max_total_len = max_total_len
@@ -159,12 +182,12 @@ class PagedDecodeEngine:
         self.attn_impl = attn_impl
         self._key = jax.random.PRNGKey(seed)
 
-        self.cache = api.init_paged_cache(num_pages, page_size,
-                                          kv_quant=kv_quant)
-        self.block_tables = jnp.full((num_slots, self.pages_per_seq), -1,
-                                     jnp.int32)
-        self.cur_token = jnp.full((num_slots,), pad_id, jnp.int32)
-        self.pos = jnp.zeros((num_slots,), jnp.int32)
+        self.cache = self._place(api.init_paged_cache(num_pages, page_size,
+                                                      kv_quant=kv_quant))
+        self.block_tables = self._place(
+            jnp.full((num_slots, self.pages_per_seq), -1, jnp.int32))
+        self.cur_token = self._place(jnp.full((num_slots,), pad_id, jnp.int32))
+        self.pos = self._place(jnp.zeros((num_slots,), jnp.int32))
         self.pool = paged.PagePool(num_pages, page_size)
         # automatic cross-prompt prefix caching (radix tree over page
         # contents); None = disabled, every page frees on release.
@@ -196,6 +219,18 @@ class PagedDecodeEngine:
         self._step = jax.jit(self._step_impl, donate_argnums=(1,))
         self._copy_pages = jax.jit(paged.copy_pages, donate_argnums=(0,))
         self._import_pages = jax.jit(paged.import_pages, donate_argnums=(0,))
+
+    def _place(self, tree):
+        """Copy to the engine's device every leaf of ``tree`` that is not
+        already there.  The copies are committed, so uncommitted operands
+        (host inputs, fresh jnp arrays) follow them into every step.  Leaves
+        already on the device are kept as they are: a replica on the
+        trainer's device shares the trainer's weight buffers."""
+        if self.device is None:
+            return tree
+        return jax.tree_util.tree_map(
+            lambda a: a if a.devices() == {self.device}
+            else jax.device_put(a, self.device), tree)
 
     # ----------------------------------------------------------- jit body
     def _step_impl(self, params, cache, cur_token, pos, decode_tables,
@@ -309,7 +344,8 @@ class PagedDecodeEngine:
         self.quant_mode = mode
 
     def update_weights(self, params) -> None:
-        self.params = quant.quantize_params(params, self.quant_mode)
+        self.params = self._place(quant.quantize_params(params,
+                                                        self.quant_mode))
         if self.quant_mode != "off":
             self.total_weight_syncs_quantized += 1
         # bump the epoch even with the cache off: slot/retained records

@@ -20,7 +20,9 @@ class OptConfig:
 
 
 def init_opt_state(params) -> Dict[str, Any]:
-    f32 = lambda p: p.astype(jnp.float32)
+    # copy=True: an fp32 leaf's astype would alias the param's buffer, and
+    # the trainer donates the optimizer state but not the params.
+    f32 = lambda p: jnp.array(p, jnp.float32, copy=True)
     return {
         "step": jnp.zeros((), jnp.int32),
         "master": jax.tree_util.tree_map(f32, params),

@@ -27,6 +27,9 @@ def make_train_state(api: ModelAPI, key) -> Dict[str, Any]:
 
 
 _CE_CHUNK = 512
+# fp32 logits elements one chunk may hold (512 MiB): at batch 16 over a
+# 151,936-row vocabulary a 512-position chunk alone would be 5 GB.
+_CE_LOGITS_BUDGET = 1 << 27
 
 
 def _unembed_matrix(api: ModelAPI, params):
@@ -41,10 +44,13 @@ def chunked_token_logprobs(features, head, tokens, *, chunk: int = _CE_CHUNK):
 
     Never materializes (B, S, V) logits: each chunk's (B, C, V) logits are
     consumed into (B, C) logprobs and rematerialized in the backward pass.
+    The chunk shrinks below ``chunk`` positions where B * C * V would pass
+    ``_CE_LOGITS_BUDGET``.
     features: (B, S, D) final-norm hidden states; returns (B, S) logprobs
     aligned with `tokens` (position 0 zero — never a response token).
     """
     b, s, d = features.shape
+    chunk = max(1, min(chunk, _CE_LOGITS_BUDGET // (b * head.shape[1])))
     x, tg = features[:, :-1], tokens[:, 1:]
     sc = s - 1
     nc = -(-sc // chunk)
@@ -138,6 +144,19 @@ def make_logprob_fn(api: ModelAPI, *, moe_mode: str = "ep"):
 # host-side wrapper: Samples -> padded arrays -> jitted steps
 # ---------------------------------------------------------------------------
 
+def _jit_donating_all_but_params(step):
+    """jit ``step(state, batch)`` so that every state entry except the
+    policy params is donated: the optimizer state (fp32 master, m and v —
+    12 of the 14 bytes a parameter costs) is updated in place instead of
+    living twice during a step.  The params are not donated, because the
+    rollout engines keep reading the tree they were handed until the next
+    weight sync.  The jitted function takes ``(params, rest, batch)``."""
+    def split_step(params, rest, batch):
+        return step(dict(rest, params=params), batch)
+
+    return jax.jit(split_step, donate_argnums=(1,))
+
+
 @dataclasses.dataclass
 class TrainerConfig:
     max_seq_len: int = 64
@@ -160,12 +179,17 @@ class HostTrainer:
             from repro.train.critic import (make_critic_train_state,
                                             make_critic_train_step)
             self.state = make_critic_train_state(api, key)
-            self._train_step = jax.jit(make_critic_train_step(
-                api, loss_cfg, opt_cfg, moe_mode=moe_mode))
+            self._train_step = _jit_donating_all_but_params(
+                make_critic_train_step(api, loss_cfg, opt_cfg,
+                                       moe_mode=moe_mode))
         else:
             self.state = make_train_state(api, key)
-            self._train_step = jax.jit(make_train_step(
-                api, loss_cfg, opt_cfg, remat=False, moe_mode=moe_mode))
+            # remat: per-layer checkpointing keeps one layer's activations
+            # live in the backward pass.  Without it, a 3-layer qwen3-4b
+            # step over 16 x 320 tokens holds ~5 GB of attention and MLP
+            # activations beside 10 GB of train state on a 16 GB chip.
+            self._train_step = _jit_donating_all_but_params(make_train_step(
+                api, loss_cfg, opt_cfg, remat=True, moe_mode=moe_mode))
         self.ref_params = ref_params  # frozen copy for KL (None = no KL)
         self._logprob_fn = jax.jit(make_logprob_fn(
             api, moe_mode="dense" if self.cfg.is_moe else "ep"))
@@ -240,7 +264,9 @@ class HostTrainer:
             for j in range(mb):
                 sl = slice(j * n // mb, (j + 1) * n // mb)
                 mini = {k: v[sl] for k, v in batch.items()}
-                self.state, m = self._train_step(self.state, mini)
+                rest = {k: v for k, v in self.state.items() if k != "params"}
+                self.state, m = self._train_step(self.state["params"], rest,
+                                                 mini)
                 metrics = {k: float(v) for k, v in m.items()}
         self.steps_done += 1
         metrics["reward_mean"] = float(np.mean([s.reward or 0.0 for s in samples]))

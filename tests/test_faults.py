@@ -343,12 +343,20 @@ def test_paged_crash_failover_greedy_parity(paged_setup):
                           for i, e in enumerate(engines)])
     router = ProxyRouter(proxies).start()
     client = RolloutClient(router)
+    # compile both replicas' steps first (one short request each): a
+    # replica's first compile can outlast the other's whole decode, which
+    # would leave nothing in flight on the replica that gets killed
+    for h in [client.submit(_task(2, p)) for p in prompts[:2]]:
+        h.result(120)
+    assert all(e.total_tokens_decoded > 0 for e in engines)
+    warm = [e.total_tokens_decoded for e in engines]
     handles = [client.submit(_task(budget, p)) for p in prompts]
     fired = []
     for h in handles:
         h.add_done_callback(fired.append)
     deadline = time.monotonic() + 60
-    while (min(e.total_tokens_decoded for e in engines) < 3
+    while (min(e.total_tokens_decoded - w
+               for e, w in zip(engines, warm, strict=True)) < 3
            and time.monotonic() < deadline):
         time.sleep(0.01)
     proxies[0].kill()

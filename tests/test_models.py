@@ -11,6 +11,7 @@ from repro.algos import LossConfig
 from repro.configs import REGISTRY, list_archs
 from repro.models import get_api
 from repro.train.optimizer import OptConfig
+from repro.train import trainer as trainer_mod
 from repro.train.trainer import make_train_state, make_train_step
 
 ALL_ARCHS = list_archs()
@@ -129,6 +130,66 @@ def test_moe_capacity_vs_dense_agree_with_headroom(rng_key):
     ld, _ = api.apply(params, batch, moe_mode="dense")
     le, _ = api.apply(params, batch, moe_mode="ep")
     np.testing.assert_allclose(np.asarray(ld), np.asarray(le), atol=1e-2)
+
+
+@pytest.mark.parametrize("budget", [7 * 64, 1 << 27])
+def test_chunked_logprobs_under_a_logits_budget(budget, monkeypatch, rng_key):
+    """Whatever chunk the logits budget leaves (1 position at the small
+    budget, the full 512 at the default), the chunked logprobs equal the
+    full-logits ones."""
+    from repro.algos import token_logprobs
+    b, s, d, v = 7, 13, 16, 64
+    kx, kh, kt = jax.random.split(rng_key, 3)
+    feats = jax.random.normal(kx, (b, s, d))
+    head = jax.random.normal(kh, (d, v))
+    tokens = jax.random.randint(kt, (b, s), 0, v)
+    monkeypatch.setattr(trainer_mod, "_CE_LOGITS_BUDGET", budget)
+    got = trainer_mod.chunked_token_logprobs(feats, head, tokens)
+    want = token_logprobs(feats[:, :-1] @ head, tokens[:, 1:])
+    np.testing.assert_allclose(np.asarray(got[:, 1:]), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    assert not np.asarray(got[:, 0]).any()
+
+
+@pytest.mark.parametrize("tied", [True, False])
+def test_embedding_init_scale(tied, rng_key):
+    """A tied table doubles as the output head, so it starts at the head's
+    1/sqrt(d_model) scale; an untied input table keeps unit scale."""
+    cfg = dataclasses.replace(tiny("qwen3-4b", d_model=256),
+                              tie_embeddings=tied)
+    params = get_api(cfg).init(rng_key)
+    assert ("lm_head" in params) is not tied
+    rms = float(jnp.sqrt(jnp.mean(params["embed"].astype(jnp.float32) ** 2)))
+    truncnorm_std = 0.88  # truncated_normal(-2, 2)
+    want = truncnorm_std * (cfg.d_model ** -0.5 if tied else 1.0)
+    assert abs(rms - want) < 0.05 * want
+
+
+def test_host_trainer_donates_optimizer_state_not_params(rng_key):
+    """The train step updates the optimizer state in place, but the params
+    handed to the rollout engines stay readable until the next sync."""
+    from repro.core.types import Sample
+    from repro.train.trainer import HostTrainer, TrainerConfig
+    api = get_api(tiny("qwen3-4b", vocab_size=32))
+    trainer = HostTrainer(api, rng_key, LossConfig("tis"),
+                          OptConfig(learning_rate=1e-2, warmup_steps=1),
+                          TrainerConfig(max_seq_len=16, group_size=2))
+    handed_out = trainer.get_weights()
+    opt_before = trainer.state["opt"]
+    rng = np.random.default_rng(0)
+    samples = [Sample(sample_id=i, prompt_id=i // 2, replica_idx=0,
+                      prompt_tokens=rng.integers(1, 32, 4),
+                      response_tokens=rng.integers(1, 32, 6),
+                      logprobs=-rng.random(6), reward=float(i % 2),
+                      group_id=i // 2) for i in range(4)]
+    metrics = trainer.train_on_samples(samples)
+    assert np.isfinite(metrics["loss"])
+    assert all(a.is_deleted() for a in jax.tree_util.tree_leaves(opt_before))
+    for old, new in zip(jax.tree_util.tree_leaves(handed_out),
+                        jax.tree_util.tree_leaves(trainer.get_weights()),
+                        strict=True):
+        assert not old.is_deleted()
+        assert old.shape == new.shape
 
 
 def test_moe_load_balance_loss_bounds(rng_key):

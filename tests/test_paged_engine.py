@@ -7,6 +7,7 @@ The two load-bearing guarantees (ISSUE acceptance criteria):
 * ABORT with retained pages → resume produces byte-identical samples to
   an uninterrupted run (no prefix re-prefill, logprobs bit-equal).
 """
+import dataclasses
 import threading
 import time
 
@@ -249,8 +250,12 @@ def test_abort_resume_audit_cycle(setup):
 @pytest.mark.kernels
 def test_engine_kernel_attention_matches_ref(setup):
     """The Pallas paged decode-attention path (interpret mode) plugged into
-    the fused engine step must produce the ref path's greedy tokens."""
+    the fused engine step must produce the ref path's greedy tokens.  In
+    float32: bf16 rounding differs between the two paths and can flip the
+    argmax of near-tied random-weight logits."""
     cfg, api, params = setup
+    api = get_api(dataclasses.replace(cfg, dtype="float32"))
+    params = jax.tree_util.tree_map(lambda a: a.astype(np.float32), params)
     outs = {}
     for impl in ("ref", "kernel_interpret"):
         eng = PagedDecodeEngine(api, params, num_slots=2, max_total_len=32,

@@ -64,10 +64,7 @@ def test_divisibility_fallback_replicates():
 
 def _abstract_mesh(data=16, model=16):
     from jax.sharding import AbstractMesh
-    try:  # jax >= 0.5: AbstractMesh(axis_sizes, axis_names)
-        return AbstractMesh((data, model), ("data", "model"))
-    except TypeError:  # jax 0.4.x: AbstractMesh(((name, size), ...))
-        return AbstractMesh((("data", data), ("model", model)))
+    return AbstractMesh((data, model), ("data", "model"))
 
 
 def test_cache_specs_batch_and_feature_sharded():
@@ -119,8 +116,6 @@ def test_build_combo_lowers_on_unit_mesh(arch, shape, monkeypatch):
         compiled = jax.jit(fn, in_shardings=in_shard, out_shardings=out_shard,
                            donate_argnums=donate).lower(*args).compile()
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):  # jax 0.4.x returns a per-device list
-        ca = ca[0]
     assert ca.get("flops", 0) > 0
 
 
