@@ -1,0 +1,106 @@
+"""From a configuration file to the program's model configuration, and
+the span and clock helpers the drivers share.  This module imports the
+program under test."""
+from __future__ import annotations
+
+import contextlib
+import threading
+import time
+from typing import Callable, Dict, List
+
+import jax
+
+from repro.models.config import ModelConfig
+
+
+def model_config(c: dict) -> ModelConfig:
+    """The program's ``ModelConfig`` for a Qwen3 dense configuration file
+    (Hugging Face key names)."""
+    if c.get("hidden_act", "silu") != "silu":
+        raise ValueError("only SwiGLU (hidden_act silu) configurations")
+    return ModelConfig(
+        arch_id=c["name"], family="dense", num_layers=c["num_hidden_layers"],
+        d_model=c["hidden_size"], num_heads=c["num_attention_heads"],
+        num_kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
+        d_ff=c["intermediate_size"], vocab_size=c["vocab_size"], qk_norm=True,
+        rope_theta=float(c["rope_theta"]), norm_eps=float(c["rms_norm_eps"]),
+        mlp_activation="swiglu", tie_embeddings=bool(c["tie_word_embeddings"]),
+        dtype=c["torch_dtype"])
+
+
+class Spans:
+    """Host spans around the benchmark's calls into the program: each is a
+    ``jax.profiler.TraceAnnotation`` (so a traced run sees it on the
+    device's clock) and a ``(start, seconds)`` record on the host clock."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.records: Dict[str, List[tuple]] = {}
+
+    @contextlib.contextmanager
+    def span(self, name: str):
+        t0 = time.monotonic()
+        with jax.profiler.TraceAnnotation(name):
+            yield
+        dt = time.monotonic() - t0
+        with self._lock:
+            self.records.setdefault(name, []).append((t0, dt))
+
+    def wrap(self, name: str, fn: Callable) -> Callable:
+        def wrapped(*a, **k):
+            with self.span(name):
+                return fn(*a, **k)
+        return wrapped
+
+    def within(self, name: str, t0: float, t1: float) -> List[float]:
+        """Durations of the ``name`` spans that started in [t0, t1)."""
+        with self._lock:
+            return [d for s, d in self.records.get(name, []) if t0 <= s < t1]
+
+
+class CompileClock:
+    """Counts backend compiles (and their seconds) while active."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self):
+        self.count = 0
+        self.seconds = 0.0
+
+    def _listen(self, event, duration, **_):
+        if event == self.EVENT:
+            self.count += 1
+            self.seconds += duration
+
+    def __enter__(self):
+        jax.monitoring.register_event_duration_secs_listener(self._listen)
+        return self
+
+    def __exit__(self, *exc):
+        jax.monitoring.unregister_event_duration_listener(self._listen)
+        return False
+
+
+class KernelLengths:
+    """Wraps a paged engine's jitted step and records, per call, the
+    written lengths of the slots the decode kernel attends for (rows of
+    the masked block table that are assigned; length = pos + 1) and the
+    prefill chunk's (start, valid tokens).  Reads device arrays, so it is
+    armed only in traced runs."""
+
+    def __init__(self, engine):
+        self.calls: List[tuple] = []     # (t, decode lengths, (start, n))
+        self.armed = False
+        self._step = engine._step
+        engine._step = self
+
+    def __call__(self, *args):
+        if self.armed:
+            import numpy as np
+            pos, tables = np.asarray(args[3]), np.asarray(args[4])
+            lengths = (pos + 1)[tables[:, 0] >= 0] if bool(args[10]) else []
+            chunk = ((int(args[7]), int(np.asarray(args[6]).sum()))
+                     if bool(args[9]) else None)
+            self.calls.append((time.monotonic(), [int(x) for x in lengths],
+                               chunk))
+        return self._step(*args)
