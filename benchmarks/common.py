@@ -1,10 +1,5 @@
-"""Shared benchmark utilities: CSV/JSON emission + calibrated workloads."""
+"""Shared benchmark utilities: CSV emission + calibrated workloads."""
 from __future__ import annotations
-
-import json
-import sys
-
-import numpy as np
 
 from repro.core import simulator as S
 
@@ -40,10 +35,3 @@ def flush_csv(path: str | None = None) -> None:
             for n, v, d in ROWS:
                 f.write(f"{n},{v},{d}\n")
 
-
-def flush_json(path: str, payload: dict) -> None:
-    """Structured benchmark output (BENCH_*.json) for machine comparison."""
-    with open(path, "w") as f:
-        json.dump(payload, f, indent=2, sort_keys=True, default=float)
-        f.write("\n")
-    print(f"wrote {path}")

@@ -10,12 +10,7 @@ from __future__ import annotations
 import argparse
 import time
 
-from benchmarks import (bench_engine, bench_fault_tolerance,
-                        bench_page_transfer, bench_paged_engine,
-                        bench_prefix_cache,
-                        bench_prefix_sharing, bench_quant,
-                        bench_queue_scheduling,
-                        bench_slo, fig1b_throughput_scaling,
+from benchmarks import (fig1b_throughput_scaling,
                         fig3_allocation_and_rollout, fig4_offpolicy_stability,
                         fig7_queue_scheduling, fig8_prompt_replication,
                         fig9_env_async, fig10_redundant_env,
@@ -32,15 +27,6 @@ MODULES = [
     ("fig10", fig10_redundant_env),
     ("fig4", fig4_offpolicy_stability),
     ("fig11", fig11_real_agentic),
-    ("engine", bench_engine),
-    ("paged_engine", bench_paged_engine),
-    ("prefix_sharing", bench_prefix_sharing),
-    ("prefix_cache", bench_prefix_cache),
-    ("queue_scheduling", bench_queue_scheduling),
-    ("page_transfer", bench_page_transfer),
-    ("fault_tolerance", bench_fault_tolerance),
-    ("slo", bench_slo),
-    ("quant", bench_quant),
     ("roofline", roofline),
 ]
 

@@ -31,6 +31,7 @@ import math
 import time
 from typing import Any, Callable, Dict, List, Optional
 
+from repro.core import tracing
 from repro.core.llm_proxy import LLMProxy
 from repro.core.router import MultiEvent
 from repro.core.sample_buffer import SampleBuffer
@@ -152,30 +153,37 @@ class AsyncController:
     # ----------------------------------------------------------------- run
     def train(self, num_steps: int, *, batch_size: Optional[int] = None,
               timeout: float = 600.0) -> List[StepStats]:
+        # program spans roll.controller.get_batch / .train / .sync cover
+        # the intervals StepStats times, and take their seconds from it
         for step in range(num_steps):
             t0 = time.monotonic()
-            batch = self.buffer.get_batch(batch_size, timeout=timeout)
+            with tracing.annotate("roll.controller.get_batch"):
+                batch = self.buffer.get_batch(batch_size, timeout=timeout)
             t1 = time.monotonic()
+            tracing.add("roll.controller.get_batch", t1 - t0)
 
-            if self.sync_mode:
-                # pause generation NOW: everything after this trains on and
-                # generates with the newest weights only
-                for p in self._targets:
-                    p.suspend()
-
-            metrics = self.train_fn(batch)
+            with tracing.annotate("roll.controller.train"):
+                if self.sync_mode:
+                    # pause generation NOW: everything after this trains on
+                    # and generates with the newest weights only
+                    for p in self._targets:
+                        p.suspend()
+                metrics = self.train_fn(batch)
             t2 = time.monotonic()
+            tracing.add("roll.controller.train", t2 - t1)
 
-            if self.sync_mode:
-                weights = self.get_weights_fn()
-                for p in self._targets:
-                    p.update_weights(weights)
-                self.buffer.advance_version()
-                for p in self._targets:
-                    p.resume()
-                sync_t = time.monotonic() - t2
-            else:
-                sync_t = self._weight_sync()
+            with tracing.annotate("roll.controller.sync"):
+                if self.sync_mode:
+                    weights = self.get_weights_fn()
+                    for p in self._targets:
+                        p.update_weights(weights)
+                    self.buffer.advance_version()
+                    for p in self._targets:
+                        p.resume()
+                    sync_t = time.monotonic() - t2
+                else:
+                    sync_t = self._weight_sync()
+            tracing.add("roll.controller.sync", sync_t)
 
             version = self.buffer.version
             staleness = [version - 1 - s.version_started for s in batch]

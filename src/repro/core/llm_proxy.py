@@ -24,6 +24,7 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Protocol
 
 from repro.analysis.sanitizer import new_lock
+from repro.core import tracing
 from repro.core.slo import SLOConfig, stamp_deadline
 from repro.core.types import (PRIORITY_NORMAL, GenerationRequest,
                               GenerationResult, NotifyingEvent, Rejected,
@@ -86,6 +87,7 @@ class LLMProxy:
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         self._idle_sleep = 0.0005
+        self._clock = time.monotonic     # receipt and admission stamps
         self._num_streaming = 0          # active requests with a stream_cb
         # cheap load metric for fleet routers: outstanding decode work in
         # tokens (unprefilled prompt + unspent budget), updated at SUBMIT
@@ -187,9 +189,10 @@ class LLMProxy:
             tasks = expand_replicas(task, n)
             if not self._admit_submission(tasks, version, callback):
                 return [t.task_id for t in tasks]
+            now = self._clock()
             reqs = [GenerationRequest(request_id=t.task_id, task=t,
                                       version_started=version,
-                                      callback=callback)
+                                      callback=callback, received_at=now)
                     for t in tasks]
             self._load_add_group(reqs)
             self._commands.put(("ADD_GROUP", _PendingGroup(reqs)))
@@ -198,7 +201,7 @@ class LLMProxy:
             return task.task_id
         req = GenerationRequest(request_id=task.task_id, task=task,
                                 version_started=version, callback=callback,
-                                stream_cb=stream_cb)
+                                stream_cb=stream_cb, received_at=self._clock())
         self._load_add(req.request_id,
                        len(task.prompt_tokens) + task.max_new_tokens)
         self._commands.put(("ADD", req))
@@ -219,8 +222,10 @@ class LLMProxy:
                    for t in tasks), "group tasks must be replicas"
         if not self._admit_submission(tasks, version, callback):
             return [t.task_id for t in tasks]
+        now = self._clock()
         reqs = [GenerationRequest(request_id=t.task_id, task=t,
-                                  version_started=version, callback=callback)
+                                  version_started=version, callback=callback,
+                                  received_at=now)
                 for t in tasks]
         self._load_add_group(reqs)
         self._commands.put(("ADD_GROUP", _PendingGroup(reqs)))
@@ -239,7 +244,8 @@ class LLMProxy:
             stamp_deadline(task, self._slo.clock())
         req = GenerationRequest(request_id=task.task_id, task=task,
                                 version_started=version, callback=callback,
-                                resume_from=resume_from, stream_cb=stream_cb)
+                                resume_from=resume_from, stream_cb=stream_cb,
+                                received_at=self._clock())
         # no prefill work: the retained pages re-attach
         self._load_add(req.request_id, task.max_new_tokens)
         self._commands.put(("ADD", req))
@@ -277,7 +283,8 @@ class LLMProxy:
             stamp_deadline(task, self._slo.clock())
         req = GenerationRequest(request_id=task.task_id, task=task,
                                 version_started=version, callback=callback,
-                                resume_from=resume_from, stream_cb=stream_cb)
+                                resume_from=resume_from, stream_cb=stream_cb,
+                                received_at=self._clock())
         # charged as a resume (no prefill); _do_transfer adds the prompt
         # back if the import fails and the request degrades to re-prefill.
         self._load_add(req.request_id, task.max_new_tokens)
@@ -470,7 +477,8 @@ class LLMProxy:
             if self._stop.is_set():
                 break
             if not self.step_once():
-                time.sleep(self._idle_sleep)
+                with tracing.span("roll.proxy.idle"):
+                    time.sleep(self._idle_sleep)
 
     def step_once(self) -> bool:
         """One proxy iteration: drain commands, admit, and — if anything is
@@ -478,16 +486,27 @@ class LLMProxy:
         is exactly this under the suspend handshake; calling it directly
         (proxy thread NOT started) drives the proxy deterministically, which
         is what lockstep fleet benchmarks and parity tests need.  Returns
-        True iff an engine step ran."""
-        self._process_commands()
-        if self._slo is not None:
-            self._watchdog_tick()
-            self._maybe_preempt()
-        self._admit_pending()
-        if not self._active:
-            return False
-        finished = self.engine.step()
-        self.steps_executed += 1
+        True iff an engine step ran.  Program spans: ``roll.proxy.step``
+        around it all, ``roll.proxy.commands``, ``roll.proxy.admit`` and
+        ``roll.proxy.publish`` (the finished requests' callbacks)."""
+        with tracing.span("roll.proxy.step"):
+            with tracing.span("roll.proxy.commands"):
+                self._process_commands()
+            if self._slo is not None:
+                self._watchdog_tick()
+                self._maybe_preempt()
+            with tracing.span("roll.proxy.admit"):
+                self._admit_pending()
+            if not self._active:
+                return False
+            finished = self.engine.step()
+            self.steps_executed += 1
+            with tracing.span("roll.proxy.publish"):
+                self._deliver(finished)
+            return True
+
+    def _deliver(self, finished) -> None:
+        """Fire the callbacks of the requests the engine step finished."""
         for rid, tokens, logprobs in finished:
             req = self._active.pop(rid, None)
             if req is None:
@@ -506,7 +525,6 @@ class LLMProxy:
                 logprobs=logprobs, version_started=req.version_started))
         if self._num_streaming > 0:
             self._publish_streams()
-        return True
 
     def _publish_streams(self) -> None:
         """Push NEWLY decoded tokens (a delta per call) of stream-subscribed
@@ -567,7 +585,8 @@ class LLMProxy:
                     imp(arg)
             elif op == "UPDATE":
                 params, done = arg
-                self.engine.update_weights(params)
+                with tracing.span("roll.proxy.apply_weights"):
+                    self.engine.update_weights(params)
                 self.staged_weight_updates += 1
                 done.set()
 
@@ -861,7 +880,7 @@ class LLMProxy:
                          t.max_new_tokens)
         return True
 
-    def _activate(self, req: GenerationRequest) -> None:
+    def _activate(self, req: GenerationRequest, now: float) -> None:
         self._active[req.request_id] = req
         # record the engine's numeric config on the task at admission time:
         # samples produced from this request carry the quantization mode
@@ -872,6 +891,11 @@ class LLMProxy:
         # its reported tokens).
         task = req.task
         if task is not None and isinstance(getattr(task, "meta", None), dict):
+            # this leg's wait from receipt to admission, summed over the
+            # legs of an aborted-and-resumed sample (continuation legs copy
+            # the meta, and the client carries the sum back)
+            task.meta["queue_wait_s"] = (task.meta.get("queue_wait_s", 0.0)
+                                         + now - req.received_at)
             task.meta["quant_mode"] = self.quant_mode
             kv = getattr(self.engine, "kv_quant", "off")
             if kv != "off":
@@ -882,6 +906,7 @@ class LLMProxy:
             self._num_streaming += 1
 
     def _admit_pending(self) -> None:
+        now = self._clock() if self._pending else 0.0
         while self._pending and self.engine.num_free_slots > 0:
             entry = self._pending[0]
             if isinstance(entry, _PendingGroup):
@@ -895,11 +920,11 @@ class LLMProxy:
                 if verdict:
                     self._pending.popleft()
                     for r in entry.requests:
-                        self._activate(r)
+                        self._activate(r, now)
                     continue
             elif self._try_admit(entry):
                 self._pending.popleft()
-                self._activate(entry)
+                self._activate(entry, now)
                 continue
             # Head is blocked (e.g. page-starved).  Resume requests further
             # back MUST be allowed to bypass it: they re-attach pages that
@@ -912,7 +937,7 @@ class LLMProxy:
                 if (isinstance(e, GenerationRequest) and e.resume_from is not None
                         and self._try_admit(e)):
                     self._pending.remove(e)
-                    self._activate(e)
+                    self._activate(e, now)
                     admitted_any = True
             if not admitted_any:
                 break

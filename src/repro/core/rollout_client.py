@@ -494,6 +494,13 @@ class RolloutClient:
             h = self._inflight.pop(res.request_id, None)
             if h is None:
                 return
+            if res.task is not None and res.task is not h.task:
+                # a continuation leg's task copied the handle's meta, and
+                # the proxy added this leg's queue wait to the copy: carry
+                # the sum back to the task the final result reports
+                wait = res.task.meta.get("queue_wait_s")
+                if wait is not None:
+                    h.task.meta["queue_wait_s"] = wait
             if not res.aborted:
                 h._append_leg(res.tokens, res.logprobs, res.version_started)
                 h._resolve(aborted=False)

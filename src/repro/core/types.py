@@ -170,6 +170,8 @@ class GenerationRequest:
     # the last watchdog tick, and the clock reading when they last grew.
     decoded_seen: int = 0
     last_progress: float = 0.0
+    # the proxy's clock when it received this leg (queue-wait stamp)
+    received_at: float = 0.0
 
 
 @dataclasses.dataclass

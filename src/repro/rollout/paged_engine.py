@@ -54,6 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import tracing
 from repro.core.types import GenerationResult
 from repro.models import paged
 from repro.models.api import ModelAPI
@@ -205,11 +206,11 @@ class PagedDecodeEngine:
         self.total_prefill_chunks = 0
         self.total_prefill_tokens = 0
         self.total_groups_forked = 0
-        # batched-dispatch accounting: fork tail copies and cross-replica
-        # transfers each issue ONE gather/scatter device call per request —
-        # ops counters stay O(requests) while page counters grow O(pages).
-        self.total_copy_ops = 0          # batched fork-tail device copies
-        self.total_pages_copied = 0      # pages moved by those copies
+        # sum over decode steps and decoding slots of the context the decode
+        # kernel attends (pos + 1), from host slot state
+        self.total_decode_context = 0
+        # cross-replica transfers issue ONE gather/scatter device call per
+        # request: ops counters stay O(requests), page counters O(pages).
         self.pages_transferred_in = 0    # cross-replica pages imported
         self.pages_transferred_out = 0   # cross-replica pages exported
         self.transfer_bytes_in = 0
@@ -549,8 +550,6 @@ class PagedDecodeEngine:
         if srcs:
             self.cache = self._copy_pages(self.cache, jnp.asarray(srcs),
                                           jnp.asarray(dsts))
-            self.total_copy_ops += 1
-            self.total_pages_copied += len(srcs)
 
     def _promote_follower(self, st: _SlotState, leader_pages: List[int]) -> None:
         """The group's prefill leader was aborted before the fork: hand its
@@ -835,99 +834,120 @@ class PagedDecodeEngine:
 
     # --------------------------------------------------------------- step
     def step(self) -> List[Tuple[int, np.ndarray, np.ndarray]]:
-        """One fused engine step; returns finished (rid, tokens, logprobs)."""
+        """One fused engine step; returns finished (rid, tokens, logprobs).
+
+        Its host phases are program spans (``repro.core.tracing``):
+        ``roll.engine.finish``, ``.prepare``, ``.dispatch``, ``.readback``
+        (the host waits for the device) and ``.update``."""
         if not self.slots:
             return []
         finished: List[Tuple[int, np.ndarray, np.ndarray]] = []
         # finish BEFORE stepping: the last sampled (or carried) token may
         # already terminate the request.
-        for slot in list(self.slots):
-            st = self.slots[slot]
-            if st.phase != _DECODE:
-                continue
-            last = st.tokens[-1] if st.tokens else st.carried_last
-            if last is not None and (last == self.eos_id or st.remaining <= 0):
-                finished.append(self._finish(slot))
+        with tracing.span("roll.engine.finish"):
+            for slot in list(self.slots):
+                st = self.slots[slot]
+                if st.phase != _DECODE:
+                    continue
+                last = st.tokens[-1] if st.tokens else st.carried_last
+                if last is not None and (last == self.eos_id
+                                         or st.remaining <= 0):
+                    finished.append(self._finish(slot))
         if not self.slots:
             return finished
 
-        prefill_slots = [s for s, st in sorted(self.slots.items())
-                         if st.phase == _PREFILL]
-        decode_slots = [s for s, st in self.slots.items()
-                        if st.phase == _DECODE]
+        with tracing.span("roll.engine.prepare"):
+            prefill_slots = [s for s, st in sorted(self.slots.items())
+                             if st.phase == _PREFILL]
+            decode_slots = [s for s, st in self.slots.items()
+                            if st.phase == _DECODE]
 
-        c = self.prefill_chunk
-        chunk_slot = None
-        n_chunk = 0
-        toks = np.full((1, c), self.pad_id, np.int32)
-        valid = np.zeros((1, c), bool)
-        start = 0
-        row = jnp.full((self.pages_per_seq,), -1, jnp.int32)
-        if prefill_slots:
-            chunk_slot = prefill_slots[self._rr % len(prefill_slots)]
-            self._rr += 1
-            st = self.slots[chunk_slot]
-            if self.prefix_cache is not None:
-                self._extend_cached_prefix(chunk_slot, st)
-            start = st.prefill_done
-            chunk = st.prompt[start:start + c]
-            n_chunk = len(chunk)
-            toks[0, :n_chunk] = chunk
-            valid[0, :n_chunk] = True
-            row = self.block_tables[chunk_slot]
+            c = self.prefill_chunk
+            chunk_slot = None
+            n_chunk = 0
+            prefill_ends = False
+            toks = np.full((1, c), self.pad_id, np.int32)
+            valid = np.zeros((1, c), bool)
+            start = 0
+            row = jnp.full((self.pages_per_seq,), -1, jnp.int32)
+            if prefill_slots:
+                chunk_slot = prefill_slots[self._rr % len(prefill_slots)]
+                self._rr += 1
+                st = self.slots[chunk_slot]
+                if self.prefix_cache is not None:
+                    self._extend_cached_prefix(chunk_slot, st)
+                start = st.prefill_done
+                chunk = st.prompt[start:start + c]
+                n_chunk = len(chunk)
+                prefill_ends = start + n_chunk >= len(st.prompt)
+                toks[0, :n_chunk] = chunk
+                valid[0, :n_chunk] = True
+                row = self.block_tables[chunk_slot]
 
-        decode_mask = np.zeros((self.num_slots,), bool)
-        decode_mask[decode_slots] = True
-        mask_j = jnp.asarray(decode_mask)
-        masked_tables = jnp.where(mask_j[:, None], self.block_tables, -1)
+            decode_mask = np.zeros((self.num_slots,), bool)
+            decode_mask[decode_slots] = True
+            mask_j = jnp.asarray(decode_mask)
+            masked_tables = jnp.where(mask_j[:, None], self.block_tables, -1)
 
-        self._key, sub = jax.random.split(self._key)
-        ptok, plp, dtok, dlp, chunk_logits, self.cache = self._step(
-            self.params, self.cache, self.cur_token, self.pos, masked_tables,
-            jnp.asarray(toks), jnp.asarray(valid),
-            jnp.asarray(start, jnp.int32), row,
-            np.bool_(chunk_slot is not None), np.bool_(bool(decode_slots)),
-            sub)
+            self._key, sub = jax.random.split(self._key)
 
-        if chunk_slot is not None:
-            st = self.slots[chunk_slot]
-            st.prefill_done += n_chunk
-            self.total_prefill_chunks += 1
-            self.total_prefill_tokens += n_chunk
-            if (self.prefix_cache is not None
-                    and st.epoch == self._weight_epoch):
-                # publish freshly completed prompt pages immediately so
-                # CONCURRENT same-prefix requests pick them up mid-prefill
-                # (lazy extension above) — the shared preamble of a batch
-                # prefills exactly once even when everything is admitted
-                # together.
-                full = st.prefill_done // self.page_size
-                if full:
-                    self.prefix_cache.insert(
-                        st.prompt[:full * self.page_size],
-                        self._slot_pages[chunk_slot][:full])
-            if st.prefill_done >= len(st.prompt):
+        with tracing.span("roll.engine.dispatch"):
+            ptok, plp, dtok, dlp, chunk_logits, self.cache = self._step(
+                self.params, self.cache, self.cur_token, self.pos,
+                masked_tables, jnp.asarray(toks), jnp.asarray(valid),
+                jnp.asarray(start, jnp.int32), row,
+                np.bool_(chunk_slot is not None), np.bool_(bool(decode_slots)),
+                sub)
+
+        # the host waits here for the device to finish the step
+        with tracing.span("roll.engine.readback"):
+            if prefill_ends:
                 t0, l0 = int(ptok[0]), float(plp[0])
-                st.phase = _DECODE
-                st.tokens.append(t0)
-                st.logprobs.append(l0)
-                st.remaining -= 1
-                self.cur_token = self.cur_token.at[chunk_slot].set(t0)
-                self.pos = self.pos.at[chunk_slot].set(len(st.prompt))
-                if st.followers:
-                    self._fork_followers(chunk_slot, chunk_logits, t0, l0)
+            if decode_slots:
+                tok_np, lp_np = np.asarray(dtok), np.asarray(dlp)
 
-        if decode_slots:
-            self.total_decode_steps += 1
-            tok_np, lp_np = np.asarray(dtok), np.asarray(dlp)
-            self.cur_token = jnp.where(mask_j, dtok, self.cur_token)
-            self.pos = jnp.where(mask_j, self.pos + 1, self.pos)
-            for s in decode_slots:
-                st = self.slots[s]
-                st.tokens.append(int(tok_np[s]))
-                st.logprobs.append(float(lp_np[s]))
-                st.remaining -= 1
-                self.total_tokens_decoded += 1
+        with tracing.span("roll.engine.update"):
+            if chunk_slot is not None:
+                st = self.slots[chunk_slot]
+                st.prefill_done += n_chunk
+                self.total_prefill_chunks += 1
+                self.total_prefill_tokens += n_chunk
+                if (self.prefix_cache is not None
+                        and st.epoch == self._weight_epoch):
+                    # publish freshly completed prompt pages immediately so
+                    # CONCURRENT same-prefix requests pick them up
+                    # mid-prefill (lazy extension above) — the shared
+                    # preamble of a batch prefills exactly once even when
+                    # everything is admitted together.
+                    full = st.prefill_done // self.page_size
+                    if full:
+                        self.prefix_cache.insert(
+                            st.prompt[:full * self.page_size],
+                            self._slot_pages[chunk_slot][:full])
+                if prefill_ends:
+                    st.phase = _DECODE
+                    st.tokens.append(t0)
+                    st.logprobs.append(l0)
+                    st.remaining -= 1
+                    self.cur_token = self.cur_token.at[chunk_slot].set(t0)
+                    self.pos = self.pos.at[chunk_slot].set(len(st.prompt))
+                    if st.followers:
+                        self._fork_followers(chunk_slot, chunk_logits, t0, l0)
+
+            if decode_slots:
+                self.total_decode_steps += 1
+                self.cur_token = jnp.where(mask_j, dtok, self.cur_token)
+                self.pos = jnp.where(mask_j, self.pos + 1, self.pos)
+                for s in decode_slots:
+                    st = self.slots[s]
+                    # the context the kernel attended: every written
+                    # position and this step's, pos + 1
+                    self.total_decode_context += (len(st.content_prefix)
+                                                  + len(st.tokens))
+                    st.tokens.append(int(tok_np[s]))
+                    st.logprobs.append(float(lp_np[s]))
+                    st.remaining -= 1
+                    self.total_tokens_decoded += 1
         return finished
 
     def _extend_cached_prefix(self, slot: int, st: _SlotState) -> None:

@@ -12,6 +12,9 @@ every test file.
 """
 import dataclasses
 import os
+import pathlib
+import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +23,9 @@ import pytest
 from repro.configs import REGISTRY
 from repro.kernels.paged_decode_attention import paged_decode_attention
 from repro.models import get_api, paged
+
+sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1] / "bench"))
+from benchlib.readings import KERNEL_OP  # noqa: E402
 
 # qwen3-4b decode at the paged engine's shapes: 16 slots, GQA 32/8 heads of
 # 128, 16-token pages, a 1,024-page pool and 64 pages (1,024 tokens) per
@@ -74,6 +80,22 @@ def test_paged_decode_attention_compiles_for_v5e(one_chip, kv_quant):
                   for k in ("k_scales", "v_scales")}
     compiled = paged_decode_attention.lower(*args, **scales).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_paged_decode_attention_keeps_its_name_on_the_v5e(one_chip):
+    """The trace names each op by its HLO instruction; the kernel's
+    roofline reader finds the kernel by this name."""
+    pages = (NUM_PAGES, PAGE, KV, D)
+    compiled = paged_decode_attention.lower(
+        _sds(one_chip, (B, H, D), jnp.bfloat16),
+        _sds(one_chip, pages, jnp.bfloat16),
+        _sds(one_chip, pages, jnp.bfloat16),
+        _sds(one_chip, (B, PAGES_PER_SEQ), jnp.int32),
+        _sds(one_chip, (B,), jnp.int32)).compile()
+    names = re.findall(r"%([\w-]+)\.\d+ = [^\n]* custom-call\([^\n]*"
+                       r'custom_call_target="tpu_custom_call"',
+                       compiled.as_text())
+    assert names == [KERNEL_OP] == ["paged_decode_attention"]
 
 
 @pytest.mark.parametrize("kv_quant", ["off", "int8"])
