@@ -209,6 +209,10 @@ class PagedDecodeEngine:
         # sum over decode steps and decoding slots of the context the decode
         # kernel attends (pos + 1), from host slot state
         self.total_decode_context = 0
+        # the pages the decode kernel reads: ceil((pos + 1) / page_size) a
+        # decoding slot a step; pages_per_seq a slot a step less this is
+        # what the kernel skips
+        self.total_decode_pages = 0
         # cross-replica transfers issue ONE gather/scatter device call per
         # request: ops counters stay O(requests), page counters O(pages).
         self.pages_transferred_in = 0    # cross-replica pages imported
@@ -942,8 +946,9 @@ class PagedDecodeEngine:
                     st = self.slots[s]
                     # the context the kernel attended: every written
                     # position and this step's, pos + 1
-                    self.total_decode_context += (len(st.content_prefix)
-                                                  + len(st.tokens))
+                    context = len(st.content_prefix) + len(st.tokens)
+                    self.total_decode_context += context
+                    self.total_decode_pages += -(-context // self.page_size)
                     st.tokens.append(int(tok_np[s]))
                     st.logprobs.append(float(lp_np[s]))
                     st.remaining -= 1

@@ -29,7 +29,7 @@ from benchlib.readings import KERNEL_OP  # noqa: E402
 
 # qwen3-4b decode at the paged engine's shapes: 16 slots, GQA 32/8 heads of
 # 128, 16-token pages, a 1,024-page pool and 64 pages (1,024 tokens) per
-# sequence.
+# sequence.  The kernel test adds the head shapes of other families.
 B, H, KV, D = 16, 32, 8, 128
 PAGE, NUM_PAGES, PAGES_PER_SEQ = 16, 1024, 64
 
@@ -64,12 +64,19 @@ def _sds(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+@pytest.mark.parametrize("h,kv,d", [
+    (H, KV, D),      # qwen3-4b: 128 key rows a page
+    (64, 4, 128),    # qwen3-moe-235b-a22b: G = 16, 64 key rows a page
+    (32, 8, 120),    # h2o-danube-3-4b: a head narrower than the lanes
+    (16, 1, 256),    # recurrentgemma-9b: MQA, 16 key rows a page
+])
 @pytest.mark.parametrize("kv_quant", ["off", "int8"])
-def test_paged_decode_attention_compiles_for_v5e(one_chip, kv_quant):
+def test_paged_decode_attention_compiles_for_v5e(one_chip, kv_quant, h, kv,
+                                                 d):
     quantized = kv_quant == "int8"
     page_dtype = jnp.int8 if quantized else jnp.bfloat16
-    pages = (NUM_PAGES, PAGE, KV, D)
-    args = (_sds(one_chip, (B, H, D), jnp.bfloat16),
+    pages = (NUM_PAGES, PAGE, kv, d)
+    args = (_sds(one_chip, (B, h, d), jnp.bfloat16),
             _sds(one_chip, pages, page_dtype),
             _sds(one_chip, pages, page_dtype),
             _sds(one_chip, (B, PAGES_PER_SEQ), jnp.int32),

@@ -67,39 +67,118 @@ def test_decode_attention_sweep(b, h, kv, s, d, dtype):
                                np.asarray(expected, np.float32), **_tol(dtype))
 
 
-def _random_block_tables(rng, b, pages_per_seq, num_pages, page_size):
-    """Random non-overlapping page assignments (page 0 = garbage, unused)."""
-    bt = np.full((b, pages_per_seq), -1, np.int32)
+PAGE, PAGES_PER_SEQ = 16, 10
+# slots: 1 token; mid-page; exactly on a page boundary; mid-page past the
+# kernel's first grid step; the whole table; a table row all -1 with a
+# length left over; a row all -1 at length 0
+LENGTHS = [1, PAGE + PAGE // 2 - 1, 2 * PAGE, 5 * PAGE + 3,
+           PAGE * PAGES_PER_SEQ, 37, 0]
+MASKED = [5, 6]
+
+
+def _paged_case(h, kv, d, rng):
+    """A shuffled pool under LENGTHS, in random physical order.  Slot 1
+    owns just the pages its length covers; the other live slots own a
+    whole table row, as a request does that reserved its budget, so pages
+    past their length are assigned.  Page 0 and a few spare pages are
+    unassigned."""
+    b = len(LENGTHS)
+    need = [0 if s in MASKED else -(-n // PAGE) if s == 1 else PAGES_PER_SEQ
+            for s, n in enumerate(LENGTHS)]
+    num_pages = 1 + sum(need) + 3
+    bt = np.full((b, PAGES_PER_SEQ), -1, np.int32)
     perm = rng.permutation(np.arange(1, num_pages))
-    i, lengths = 0, []
-    for bi in range(b):
-        n = int(rng.integers(1, pages_per_seq + 1))
-        bt[bi, :n] = perm[i:i + n]
+    i = 0
+    for s, n in enumerate(need):
+        bt[s, :n] = perm[i:i + n]
         i += n
-        lengths.append(int(rng.integers(1, n * page_size + 1)))
-    return jnp.asarray(bt), jnp.asarray(lengths, jnp.int32)
+    q = rng.normal(size=(b, h, d)).astype(np.float32)
+    kp = rng.normal(size=(num_pages, PAGE, kv, d)).astype(np.float32)
+    vp = rng.normal(size=(num_pages, PAGE, kv, d)).astype(np.float32)
+    return q, kp, vp, bt, np.asarray(LENGTHS, np.int32)
 
 
-@pytest.mark.parametrize("b,h,kv,d,page_size,pages_per_seq", [
-    (2, 8, 2, 64, 16, 4),    # GQA
-    (3, 4, 4, 64, 32, 2),    # MHA
-    (1, 8, 1, 128, 16, 6),   # MQA
+def _quantize_pages(x):
+    """Per-(token, kv-head) symmetric int8 pages + fp32 scales, the same
+    scheme ``paged.quantize_kv`` writes (scales laid out (N, page, kv))."""
+    xf = np.asarray(x, np.float32)
+    amax = np.abs(xf).max(-1)
+    scale = np.maximum(amax, 1e-12) / 127.0
+    codes = np.clip(np.round(xf / scale[..., None]), -127, 127).astype(np.int8)
+    return codes, scale.astype(np.float32)
+
+
+def _kernel_operands(q, kp, vp, kv_dtype):
+    """(q, k pages, v pages, scales kwargs) as the kernel takes them: int8
+    pages with a float32 query, else query and pages in ``kv_dtype``."""
+    if kv_dtype == "int8":
+        (kc, ks), (vc, vs) = _quantize_pages(kp), _quantize_pages(vp)
+        return (jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc),
+                dict(k_scales=jnp.asarray(ks), v_scales=jnp.asarray(vs)))
+    dt = jnp.dtype(kv_dtype)
+    return (jnp.asarray(q, dt), jnp.asarray(kp, dt), jnp.asarray(vp, dt), {})
+
+
+@pytest.mark.parametrize("g,kv,d", [
+    (4, 8, 128),     # qwen3-4b
+    (16, 4, 128),    # qwen3-moe
+    (4, 8, 120),     # h2o-danube-3-4b
+    (1, 4, 128),     # MHA
+    (16, 1, 120),    # MQA, 16 key rows a page
+    (1, 1, 128),
 ])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_decode_attention_sweep(b, h, kv, d, page_size, pages_per_seq,
-                                      dtype):
-    num_pages = 1 + b * pages_per_seq
-    q = jax.random.normal(KEY, (b, h, d), dtype)
-    kp = jax.random.normal(jax.random.fold_in(KEY, 1),
-                           (num_pages, page_size, kv, d), dtype)
-    vp = jax.random.normal(jax.random.fold_in(KEY, 2),
-                           (num_pages, page_size, kv, d), dtype)
-    bt, lengths = _random_block_tables(np.random.default_rng(0), b,
-                                       pages_per_seq, num_pages, page_size)
-    out = paged_decode_attention(q, kp, vp, bt, lengths, interpret=True)
-    expected = ref.paged_decode_attention_ref(q, kp, vp, bt, lengths)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(expected, np.float32), **_tol(dtype))
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+@pytest.mark.parametrize("softcap", [None, 30.0])
+def test_paged_decode_attention_sweep(g, kv, d, kv_dtype, softcap):
+    """The kernel against the gathered jnp oracle, at every fill level of
+    LENGTHS; int8 pages against the oracle on the same codes and scales,
+    and within int8 error of the float pages.  A slot with no assigned
+    page under its length reads 0 (the oracle averages page 0 there)."""
+    rng = np.random.default_rng(g * 100 + kv * 10 + d)
+    q, kp, vp, bt, lengths = _paged_case(g * kv, kv, d, rng)
+    qk, kk, vk, scales = _kernel_operands(q, kp, vp, kv_dtype)
+    bt, lengths = jnp.asarray(bt), jnp.asarray(lengths)
+    out = np.asarray(paged_decode_attention(
+        qk, kk, vk, bt, lengths, softcap=softcap, interpret=True, **scales),
+        np.float32)
+    expected = np.asarray(ref.paged_decode_attention_ref(
+        qk, kk, vk, bt, lengths, softcap=softcap, **scales), np.float32)
+    live = [s for s in range(len(LENGTHS)) if s not in MASKED]
+    tol = _tol(jnp.bfloat16 if kv_dtype == "bfloat16" else jnp.float32)
+    np.testing.assert_allclose(out[live], expected[live], **tol)
+    assert not out[MASKED].any()
+    if kv_dtype == "int8":
+        fp = ref.paged_decode_attention_ref(
+            jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), bt, lengths,
+            softcap=softcap)
+        np.testing.assert_allclose(out[live], np.asarray(fp)[live],
+                                   rtol=0.05, atol=0.05)
+
+
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+def test_paged_decode_attention_never_reads_dead_pages(kv_dtype):
+    """NaN in every page past each slot's length and in every unassigned
+    page (page 0 included): the output is finite and equals the clean
+    pool's, so dead pages are neither used nor leak through a 0 weight."""
+    rng = np.random.default_rng(3)
+    q, kp, vp, bt, lengths = _paged_case(32, 8, 128, rng)
+    qk, kk, vk, scales = _kernel_operands(q, kp, vp, kv_dtype)
+    read = {int(p) for s, n in enumerate(lengths)
+            for p in bt[s, :-(-n // PAGE)] if p >= 0}
+    dead = np.asarray([p for p in range(kp.shape[0]) if p not in read])
+    if kv_dtype == "int8":     # int8 codes hold no NaN: poison the scales
+        poisoned = (kk, vk, {k: v.at[dead].set(jnp.nan)
+                             for k, v in scales.items()})
+    else:
+        poisoned = (kk.at[dead].set(jnp.nan), vk.at[dead].set(jnp.nan), {})
+    bt, lengths = jnp.asarray(bt), jnp.asarray(lengths)
+    clean = paged_decode_attention(qk, kk, vk, bt, lengths, interpret=True,
+                                   **scales)
+    out = paged_decode_attention(qk, poisoned[0], poisoned[1], bt, lengths,
+                                 interpret=True, **poisoned[2])
+    assert np.isfinite(np.asarray(out, np.float32)).all()
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(clean, np.float32))
 
 
 def test_paged_decode_attention_matches_dense_decode():
@@ -119,85 +198,6 @@ def test_paged_decode_attention_matches_dense_decode():
                           v.reshape(b * pages_per_seq, page_size, kv, d)])
     out = paged_decode_attention(q, kp, vp, bt, lengths, interpret=True)
     expected = ref.decode_attention_ref(q, k, v, lengths)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
-                               rtol=2e-5, atol=2e-5)
-
-
-def test_paged_decode_attention_softcap():
-    b, h, kv, d, page_size, pages_per_seq = 2, 4, 2, 64, 16, 3
-    num_pages = 1 + b * pages_per_seq
-    q = jax.random.normal(KEY, (b, h, d))
-    kp = jax.random.normal(jax.random.fold_in(KEY, 1),
-                           (num_pages, page_size, kv, d))
-    vp = jax.random.normal(jax.random.fold_in(KEY, 2),
-                           (num_pages, page_size, kv, d))
-    bt, lengths = _random_block_tables(np.random.default_rng(1), b,
-                                       pages_per_seq, num_pages, page_size)
-    out = paged_decode_attention(q, kp, vp, bt, lengths, softcap=30.0,
-                                 interpret=True)
-    expected = ref.paged_decode_attention_ref(q, kp, vp, bt, lengths,
-                                              softcap=30.0)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
-                               rtol=2e-5, atol=2e-5)
-
-
-def _quantize_pages(x):
-    """Per-(token, kv-head) symmetric int8 pages + fp32 scales, the same
-    scheme ``paged.quantize_kv`` writes (scales laid out (N, page, kv))."""
-    xf = np.asarray(x, np.float32)
-    amax = np.abs(xf).max(-1)
-    scale = np.maximum(amax, 1e-12) / 127.0
-    codes = np.clip(np.round(xf / scale[..., None]), -127, 127).astype(np.int8)
-    return jnp.asarray(codes), jnp.asarray(scale, jnp.float32)
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("b,h,kv,d,page_size,pages_per_seq", [
-    (2, 8, 2, 64, 16, 4),    # GQA
-    (3, 4, 4, 64, 32, 2),    # MHA
-    (1, 8, 1, 128, 16, 6),   # MQA
-])
-def test_paged_decode_attention_int8_sweep(b, h, kv, d, page_size,
-                                           pages_per_seq):
-    """Quantized kernel (dequant-in-kernel) vs the quantized jnp oracle."""
-    num_pages = 1 + b * pages_per_seq
-    q = jax.random.normal(KEY, (b, h, d))
-    kp_f = jax.random.normal(jax.random.fold_in(KEY, 1),
-                             (num_pages, page_size, kv, d))
-    vp_f = jax.random.normal(jax.random.fold_in(KEY, 2),
-                             (num_pages, page_size, kv, d))
-    kp, ks = _quantize_pages(kp_f)
-    vp, vs = _quantize_pages(vp_f)
-    bt, lengths = _random_block_tables(np.random.default_rng(0), b,
-                                       pages_per_seq, num_pages, page_size)
-    out = paged_decode_attention(q, kp, vp, bt, lengths,
-                                 k_scales=ks, v_scales=vs, interpret=True)
-    expected = ref.paged_decode_attention_ref(q, kp, vp, bt, lengths,
-                                              k_scales=ks, v_scales=vs)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
-                               rtol=2e-5, atol=2e-5)
-    # and the whole quantized path must track the fp oracle within int8 error
-    fp = ref.paged_decode_attention_ref(q, kp_f, vp_f, bt, lengths)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(fp),
-                               rtol=0.05, atol=0.05)
-
-
-@pytest.mark.slow
-def test_paged_decode_attention_int8_softcap():
-    b, h, kv, d, page_size, pages_per_seq = 2, 4, 2, 64, 16, 3
-    num_pages = 1 + b * pages_per_seq
-    q = jax.random.normal(KEY, (b, h, d))
-    kp, ks = _quantize_pages(jax.random.normal(
-        jax.random.fold_in(KEY, 1), (num_pages, page_size, kv, d)))
-    vp, vs = _quantize_pages(jax.random.normal(
-        jax.random.fold_in(KEY, 2), (num_pages, page_size, kv, d)))
-    bt, lengths = _random_block_tables(np.random.default_rng(1), b,
-                                       pages_per_seq, num_pages, page_size)
-    out = paged_decode_attention(q, kp, vp, bt, lengths, k_scales=ks,
-                                 v_scales=vs, softcap=30.0, interpret=True)
-    expected = ref.paged_decode_attention_ref(q, kp, vp, bt, lengths,
-                                              k_scales=ks, v_scales=vs,
-                                              softcap=30.0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
                                rtol=2e-5, atol=2e-5)
 

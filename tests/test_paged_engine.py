@@ -247,6 +247,37 @@ def test_abort_resume_audit_cycle(setup):
     assert eng.num_free_pages == eng.num_pages - 1
 
 
+def test_total_decode_pages_is_the_sum_of_live_pages(setup):
+    """The pages the decode kernel reads: ceil((pos + 1) / page_size) for
+    every decoding slot on every step, across a finish and the admission
+    that takes the freed slot."""
+    _, api, params = setup
+    eng = PagedDecodeEngine(api, params, num_slots=2, max_total_len=32,
+                            page_size=4, prefill_chunk=4, eos_id=99,
+                            temperature=0.0)
+    rng = np.random.default_rng(0)
+    eng.add_request(0, rng.integers(1, 32, 3), 4)
+    eng.add_request(1, rng.integers(1, 32, 6), 16)
+    expect, done, admitted = 0, set(), False
+    for _ in range(60):
+        pos = np.asarray(eng.pos)
+        # a slot with no budget left finishes before the step decodes
+        expect += sum(-(-(int(pos[s]) + 1) // 4)
+                      for s, st in eng.slots.items()
+                      if st.phase == "decode" and st.remaining > 0)
+        done |= {rid for rid, _, _ in eng.step()}
+        if 0 in done and not admitted:
+            eng.add_request(2, rng.integers(1, 32, 9), 8)
+            admitted = True
+        if done == {0, 1, 2}:
+            break
+    assert done == {0, 1, 2}
+    assert eng.total_decode_pages == expect > eng.total_decode_steps
+    # the count sits between one page a token and the table's width
+    assert (eng.total_tokens_decoded <= eng.total_decode_pages
+            <= eng.pages_per_seq * eng.total_tokens_decoded)
+
+
 @pytest.mark.kernels
 def test_engine_kernel_attention_matches_ref(setup):
     """The Pallas paged decode-attention path (interpret mode) plugged into
