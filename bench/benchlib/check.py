@@ -42,15 +42,17 @@ def _check(value: float, limit: float) -> dict:
     return {"value": float(value), "limit": float(limit)}
 
 
-def served(cfg: dict, seed: int, samples, limits: dict, cast=None) -> dict:
-    """``samples``: ``(prompt, served tokens, recorded logprobs)``."""
+def served(cfg: dict, arch, seed: int, samples, limits: dict,
+           cast=None) -> dict:
+    """``samples``: ``(prompt, served tokens, recorded logprobs)``; ``arch``
+    the configuration's architecture module."""
     seqs = [np.concatenate([np.asarray(p, np.int32).ravel(),
                             np.asarray(r, np.int32).ravel()])
             for p, r, _ in samples]
     t = max(len(s) for s in seqs)
-    rows = max(1, ATTN_SCORE_BUDGET // (t * t * cfg["num_attention_heads"] * 4))
-    ref = reference.served_logprobs(cfg, seed, seqs, rows=min(rows, len(seqs)),
-                                    cast=cast)
+    rows = max(1, ATTN_SCORE_BUDGET // (t * t * arch.dims(cfg).heads * 4))
+    ref = reference.served_logprobs(cfg, arch, seed, seqs,
+                                    rows=min(rows, len(seqs)), cast=cast)
     gap = 0.0
     for (p, r, lp), rl in zip(samples, ref, strict=True):
         n = len(np.asarray(p).ravel())
@@ -76,8 +78,8 @@ def _half(feed: dict) -> dict:
     return {k: v[:n] for k, v in feed.items()}
 
 
-def training(cfg: dict, seed: int, feeds: list, progress: dict, mix: dict,
-             controls=()) -> dict:
+def training(cfg: dict, arch, seed: int, feeds: list, progress: dict,
+             mix: dict, controls=()) -> dict:
     """The reference follows the first optimizer steps on the same feeds;
     ``progress`` holds the program's readings (losses, first-gradient leaf
     norms, float32 parameters after the last warm step).  Each name in
@@ -85,11 +87,11 @@ def training(cfg: dict, seed: int, feeds: list, progress: dict, mix: dict,
     place, suffixed with the name: ``fp8`` computed with float8 matmul
     operands, ``half_batch`` fed half of each batch."""
     limits = mix["limits"]
-    run = functools.partial(reference.train_steps, cfg, seed,
+    run = functools.partial(reference.train_steps, cfg, arch, seed,
                             loss_cfg=mix["loss"], opt=mix["optimizer"])
     ref = run(feeds)
     ref_params = ref.pop("params")
-    ref_change = reference.change_norms(cfg, seed, ref_params)
+    ref_change = reference.change_norms(cfg, arch, seed, ref_params)
     grads = ref["grad_norm"]
     med = float(np.median(list(grads.values())))
     keep = [n for n, v in grads.items() if v >= 1e-3 * med]
@@ -106,7 +108,7 @@ def training(cfg: dict, seed: int, feeds: list, progress: dict, mix: dict,
         readings["." + name] = prog
     out = {}
     for suffix, prog in readings.items():
-        change = reference.change_norms(cfg, seed, prog.pop("master"))
+        change = reference.change_norms(cfg, arch, seed, prog.pop("master"))
         loss_gap = max(abs(a - b) for a, b in
                        zip(prog["loss"], ref["loss"], strict=True)) / scale
         print(f"bench: train_loss_gap{suffix}={loss_gap!r} (not compared)",
@@ -123,9 +125,10 @@ def training(cfg: dict, seed: int, feeds: list, progress: dict, mix: dict,
         raise ValueError(f"the engines' weights were read after "
                          f"{progress['synced_after']} optimizer steps, the "
                          f"reference follows {len(feeds)}")
-    ref_served = reference.change_norms(cfg, seed, ref_params, served=True)
+    ref_served = reference.change_norms(cfg, arch, seed, ref_params,
+                                        served=True)
     del ref_params
-    gap = max(norm_gap(reference.change_norms(cfg, seed, w, served=True),
+    gap = max(norm_gap(reference.change_norms(cfg, arch, seed, w, served=True),
                        ref_served, keep, "synced_change_gap")
               for w in progress["synced"])
     out["synced_change_gap"] = _check(gap, limits["synced_change_gap"])

@@ -14,8 +14,14 @@ from typing import Iterable
 
 @dataclasses.dataclass(frozen=True)
 class Dims:
-    """A dense GQA transformer's sizes (Hugging Face ``config.json`` names
-    in ``from_config``)."""
+    """A dense GQA transformer's sizes.  An architecture module
+    (``bench/arch/<name>.py``) reads them from its configuration file in
+    ``dims(c)``; one whose layers multiply through other parameters (a
+    mixture of experts: the k active experts and the router) returns an
+    object whose ``layer_matmul_params`` counts those.  The counts below
+    read only ``matmul_params``, ``trunk_params``, ``head_params``,
+    ``attn_flops``, ``heads``, ``kv_heads``, ``head_dim``, ``layers``,
+    ``vocab`` and ``d_model``."""
     d_model: int
     layers: int
     heads: int
@@ -23,13 +29,6 @@ class Dims:
     head_dim: int
     d_ff: int
     vocab: int
-
-    @classmethod
-    def from_config(cls, c: dict) -> "Dims":
-        return cls(d_model=c["hidden_size"], layers=c["num_hidden_layers"],
-                   heads=c["num_attention_heads"],
-                   kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
-                   d_ff=c["intermediate_size"], vocab=c["vocab_size"])
 
     @property
     def layer_matmul_params(self) -> int:

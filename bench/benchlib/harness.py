@@ -2,7 +2,17 @@
 cell's driver, reads the per-layer metrics and prints the result.
 
 Files, each found by the name ``BENCHMARK.json`` gives it:
-- ``bench/configs/<config>.json``: the model's sizes as run;
+- ``bench/configs/<config>.json``: the model's sizes as run, in the
+  Hugging Face ``config.json``'s key names;
+- ``bench/arch/<architectures[0]>.py``: what depends on the configuration's
+  architecture, named by its own ``architectures`` key
+  (``Qwen3ForCausalLM``): ``program_config(c)``, the program's model
+  configuration; ``dims(c)``, the sizes ``benchlib.counts`` reads;
+  ``layer_specs(dims)`` and ``top_specs(dims, tied)``, the weights'
+  leaves as ``(path, shape, kind, scale)``; and ``block(w, x, dims, i,
+  theta, eps, cast) -> (x, extra_loss)``, layer ``i`` (traced) of the
+  plain reference and its float32 extra loss (a router's; 0.0 where
+  there is none), summed over the layers into the reference's loss;
 - ``bench/traffic/<traffic>.json``: the mix's parameters, including the
   driver kind that serves it;
 - ``bench/drivers/<driver>.py``: ``run(ctx) -> Outcome``;
@@ -48,6 +58,7 @@ class Context:
     bench: dict
     cell: dict
     config: dict
+    arch: Any                         # the configuration's bench/arch module
     mix: dict
     seed: int
     seconds: float
@@ -82,6 +93,17 @@ def find_cell(bench: dict, name: str) -> dict:
     raise KeyError(f"no workload {name!r} in BENCHMARK.json")
 
 
+def load_arch(config: dict, root: pathlib.Path = ROOT):
+    """The architecture module that ``config["architectures"][0]`` names."""
+    name = config["architectures"][0]
+    path = root / "bench" / "arch" / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"no architecture module bench/arch/{name}.py for configuration "
+            f"{config.get('name')!r} (looked for {path})")
+    return load_module(path, f"bench_arch_{name}")
+
+
 def cell_context(name: str, *, seed: int, seconds: float, trace: bool,
                  t_start: float, devices: list, peaks: Optional[dict],
                  root: pathlib.Path = ROOT, control: Optional[str] = None,
@@ -89,8 +111,9 @@ def cell_context(name: str, *, seed: int, seconds: float, trace: bool,
     bench = load_json(root / "BENCHMARK.json")
     cell = find_cell(bench, name)
     bdir = root / "bench"
-    return Context(name=name, bench=bench, cell=cell,
-                   config=load_json(bdir / "configs" / f"{cell['config']}.json"),
+    config = load_json(bdir / "configs" / f"{cell['config']}.json")
+    return Context(name=name, bench=bench, cell=cell, config=config,
+                   arch=load_arch(config, root),
                    mix=load_json(bdir / "traffic" / f"{cell['traffic']}.json"),
                    seed=seed, seconds=seconds, trace=trace, t_start=t_start,
                    devices=devices, peaks=peaks, control=control,

@@ -1,6 +1,7 @@
-"""From a configuration file to the program's model configuration, and
-the span and clock helpers the drivers share.  This module imports the
-program under test."""
+"""The span and clock helpers the drivers share, around the program's
+objects.  The program's model configuration comes from the
+configuration's architecture module (``bench/arch/<name>.py``,
+``program_config``)."""
 from __future__ import annotations
 
 import contextlib
@@ -9,23 +10,6 @@ import time
 from typing import Callable, Dict, List
 
 import jax
-
-from repro.models.config import ModelConfig
-
-
-def model_config(c: dict) -> ModelConfig:
-    """The program's ``ModelConfig`` for a Qwen3 dense configuration file
-    (Hugging Face key names)."""
-    if c.get("hidden_act", "silu") != "silu":
-        raise ValueError("only SwiGLU (hidden_act silu) configurations")
-    return ModelConfig(
-        arch_id=c["name"], family="dense", num_layers=c["num_hidden_layers"],
-        d_model=c["hidden_size"], num_heads=c["num_attention_heads"],
-        num_kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
-        d_ff=c["intermediate_size"], vocab_size=c["vocab_size"], qk_norm=True,
-        rope_theta=float(c["rope_theta"]), norm_eps=float(c["rms_norm_eps"]),
-        mlp_activation="swiglu", tie_embeddings=bool(c["tie_word_embeddings"]),
-        dtype=c["torch_dtype"])
 
 
 class Spans:

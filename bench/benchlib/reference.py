@@ -1,6 +1,11 @@
-"""The plain reference: a dense GQA transformer (Qwen3: RMSNorm, q/k norm,
-half-split RoPE, SwiGLU) in float32 at "highest" matmul precision, and the
-GRPO train step it is trained with.
+"""The plain reference: a causal language model in float32 at "highest"
+matmul precision, and the GRPO train step it is trained with.
+
+What is shared lives here: the embedding, the final norm and the head,
+the layer loop, the loss, AdamW, and the pieces blocks are built from
+(``mm``, ``rmsnorm``, ``rope``, ``attention``).  The block itself is the
+architecture module's (``bench/arch/<name>.py``, ``block``), which every
+function here that runs the model takes as ``arch``.
 
 It imports nothing of the program and reads no weight the program holds:
 weights come from ``benchlib.weights`` and the seed.  ``cast`` (optional)
@@ -16,10 +21,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchlib import weights as W
-from benchlib.counts import Dims
 
 HI = jax.lax.Precision.HIGHEST
 Cast = Optional[Callable]
+NO_EXTRA_LOSS = np.float32(0.0)     # a block's extra loss where it has none
 
 
 def fp8_cast(a):
@@ -33,7 +38,7 @@ def fp8_cast(a):
     return a + jax.lax.stop_gradient(q - a)
 
 
-def _mm(a, b, cast: Cast):
+def mm(a, b, cast: Cast):
     if cast is not None:
         a, b = cast(a), cast(b)
     return jnp.matmul(a, b, precision=HI)
@@ -55,18 +60,13 @@ def rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def block(w, x, dims: Dims, theta: float, eps: float, cast: Cast = None):
-    """One causal block over x: (B, T, D) at positions 0..T-1."""
-    b, t, _ = x.shape
-    hd, kv = dims.head_dim, dims.kv_heads
-    g = dims.heads // kv
-    h = rmsnorm(x, w["ln1"]["scale"], eps)
-    a = w["attn"]
-    q = _mm(h, a["wq"], cast).reshape(b, t, dims.heads, hd)
-    k = _mm(h, a["wk"], cast).reshape(b, t, kv, hd)
-    v = _mm(h, a["wv"], cast).reshape(b, t, kv, hd)
-    q = rope(rmsnorm(q, a["q_norm"], eps), theta).reshape(b, t, kv, g, hd)
-    k = rope(rmsnorm(k, a["k_norm"], eps), theta)
+def attention(q, k, v, cast: Cast = None):
+    """Causal GQA softmax attention at positions 0..T-1: q (B, T, heads,
+    hd), k and v (B, T, kv, hd), each head of q on kv head ``h // (heads //
+    kv)``; returns (B, T, heads * hd)."""
+    b, t, heads, hd = q.shape
+    kv = k.shape[2]
+    q = q.reshape(b, t, kv, heads // kv, hd)
     if cast is not None:
         q, k, v = cast(q), cast(k), cast(v)
     s = jnp.einsum("btkgd,bskd->bkgts", q, k, precision=HI) * hd ** -0.5
@@ -76,11 +76,7 @@ def block(w, x, dims: Dims, theta: float, eps: float, cast: Cast = None):
     if cast is not None:
         p = cast(p)
     o = jnp.einsum("bkgts,bskd->btkgd", p, v, precision=HI)
-    x = x + _mm(o.reshape(b, t, dims.heads * hd), a["wo"], cast)
-    h = rmsnorm(x, w["ln2"]["scale"], eps)
-    m = w["mlp"]
-    y = jax.nn.silu(_mm(h, m["wi_gate"], cast)) * _mm(h, m["wi_up"], cast)
-    return x + _mm(y, m["wo"], cast)
+    return o.reshape(b, t, heads * hd)
 
 
 def head_matrix(top: dict, tied: bool):
@@ -93,7 +89,7 @@ def token_logprobs(xf, head, tokens, cast: Cast = None):
     block at a time, never (B, T, V)."""
     def row(args):
         x, tok = args
-        logits = _mm(x[:-1], head, cast)
+        logits = mm(x[:-1], head, cast)
         lp = jax.nn.log_softmax(logits, -1)
         picked = jnp.take_along_axis(lp, tok[1:, None], -1)[:, 0]
         return jnp.concatenate([jnp.zeros((1,), jnp.float32), picked])
@@ -104,13 +100,13 @@ def token_logprobs(xf, head, tokens, cast: Cast = None):
 # served tokens: layer by layer, so only one block's weights are held
 # --------------------------------------------------------------------------
 
-def served_logprobs(cfg: dict, seed: int, seqs, *, rows: int,
+def served_logprobs(cfg: dict, arch, seed: int, seqs, *, rows: int,
                     cast: Cast = None) -> list:
     """Reference logprobs of every position of each token sequence in
     ``seqs`` (int arrays): ``out[i][t]`` = log p(seqs[i][t] | seqs[i][:t]).
     Sequences are right-padded to one length (causal: padding never
     reaches an earlier position) and run ``rows`` at a time."""
-    dims = Dims.from_config(cfg)
+    dims = arch.dims(cfg)
     tied = bool(cfg["tie_word_embeddings"])
     theta, eps = float(cfg["rope_theta"]), float(cfg["rms_norm_eps"])
     t = max(len(s) for s in seqs)
@@ -120,14 +116,14 @@ def served_logprobs(cfg: dict, seed: int, seqs, *, rows: int,
         toks[i, :len(s)] = s
     toks = toks.reshape(n // rows, rows, t)
 
-    top = W.top(dims, tied, seed)
+    top = W.top(arch, dims, tied, seed)
     with jax.default_matmul_precision("highest"):
         xs = [jnp.take(top["embed"], jnp.asarray(c), axis=0) for c in toks]
-        step = jax.jit(functools.partial(block, dims=dims, theta=theta,
+        step = jax.jit(functools.partial(arch.block, dims=dims, theta=theta,
                                          eps=eps, cast=cast))
         for i in range(dims.layers):
-            w = W.layer(dims, seed, i)
-            xs = [step(w, x) for x in xs]
+            w = W.layer(arch, dims, seed, i)
+            xs = [step(w, x, i=np.int32(i))[0] for x in xs]
             del w
         head = head_matrix(top, tied)
         lp_fn = jax.jit(functools.partial(token_logprobs, cast=cast))
@@ -142,24 +138,31 @@ def served_logprobs(cfg: dict, seed: int, seqs, *, rows: int,
 # the train step: GRPO's clipped surrogate and AdamW, as the mix states them
 # --------------------------------------------------------------------------
 
-def forward_logprobs(params, tokens, cfg: dict, cast: Cast = None):
-    dims = Dims.from_config(cfg)
+def forward_logprobs(params, tokens, cfg: dict, arch, cast: Cast = None):
+    """(token logprobs, the sum over the layers of each block's extra loss)."""
+    dims = arch.dims(cfg)
     tied = bool(cfg["tie_word_embeddings"])
     theta, eps = float(cfg["rope_theta"]), float(cfg["rms_norm_eps"])
     x = jnp.take(params["embed"], tokens, axis=0)
 
-    def body(h, w):
-        return block(w, h, dims, theta, eps, cast), None
+    def body(h, layer):
+        w, i = layer
+        return arch.block(w, h, dims, i, theta, eps, cast)
 
-    x, _ = jax.lax.scan(jax.checkpoint(body), x, params["blocks"])
+    x, extra = jax.lax.scan(jax.checkpoint(body), x,
+                            (params["blocks"], jnp.arange(dims.layers)))
     xf = rmsnorm(x, params["final_norm"]["scale"], eps)
-    return token_logprobs(xf, head_matrix(params, tied), tokens, cast)
+    return (token_logprobs(xf, head_matrix(params, tied), tokens, cast),
+            extra.sum())
 
 
-def grpo_loss(params, batch, cfg: dict, loss_cfg: dict, cast: Cast = None):
+def grpo_loss(params, batch, cfg: dict, arch, loss_cfg: dict,
+              cast: Cast = None):
     """Token-level clipped surrogate, per-sequence 1/|o| averaging, with the
-    advantages weighted by min(pi_train / pi_rollout, cap) (stop-gradient)."""
-    lp = forward_logprobs(params, batch["tokens"], cfg, cast)
+    advantages weighted by min(pi_train / pi_rollout, cap) (stop-gradient),
+    plus the blocks' extra loss (a router's auxiliary loss; 0 for dense
+    blocks)."""
+    lp, extra = forward_logprobs(params, batch["tokens"], cfg, arch, cast)
     old, mask = batch["old_logprobs"], batch["mask"]
     adv = batch["advantages"] * jnp.minimum(
         jnp.exp(jax.lax.stop_gradient(lp) - old), loss_cfg["mismatch_cap"])
@@ -167,7 +170,7 @@ def grpo_loss(params, batch, cfg: dict, loss_cfg: dict, cast: Cast = None):
     eps = loss_cfg["clip_eps"]
     obj = jnp.minimum(ratio * adv, jnp.clip(ratio, 1 - eps, 1 + eps) * adv)
     per_seq = (obj * mask).sum(-1) / jnp.maximum(mask.sum(-1), 1.0)
-    return -per_seq.mean()
+    return -per_seq.mean() + extra
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 2, 3))
@@ -184,8 +187,8 @@ def _sq_norm(tree):
     return sum(jnp.sum(g * g) for g in jax.tree_util.tree_leaves(tree))
 
 
-def train_steps(cfg: dict, seed: int, feeds: list, loss_cfg: dict, opt: dict,
-                cast: Cast = None) -> dict:
+def train_steps(cfg: dict, arch, seed: int, feeds: list, loss_cfg: dict,
+                opt: dict, cast: Cast = None) -> dict:
     """The reference's run of the first ``len(feeds)`` optimizer steps from
     the seed's weights (AdamW with global-norm clipping and linear warmup):
     each step's loss, each leaf's norm of the first (clipped) gradient, and
@@ -193,16 +196,15 @@ def train_steps(cfg: dict, seed: int, feeds: list, loss_cfg: dict, opt: dict,
     (``weights.per_layer_leaves``).  Adam's moments live on the host and
     visit the device one leaf at a time, so the device holds only the
     parameters and one gradient."""
-    dims = Dims.from_config(cfg)
     tied = bool(cfg["tie_word_embeddings"])
-    params = jax.tree_util.tree_map(
-        lambda a: a.astype(jnp.float32), W.make(dims, tied, seed))
+    params = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32),
+                                    W.make(arch, arch.dims(cfg), tied, seed))
     leaves, treedef = jax.tree_util.tree_flatten(params)
     del params
     m = [np.zeros(a.shape, np.float32) for a in leaves]
     v = [np.zeros(a.shape, np.float32) for a in leaves]
     vg = jax.jit(jax.value_and_grad(functools.partial(
-        grpo_loss, cfg=cfg, loss_cfg=loss_cfg, cast=cast)))
+        grpo_loss, cfg=cfg, arch=arch, loss_cfg=loss_cfg, cast=cast)))
     b1, b2 = opt["b1"], opt["b2"]
     losses, grad_norms = [], None
     with jax.default_matmul_precision("highest"):
@@ -229,14 +231,14 @@ def train_steps(cfg: dict, seed: int, feeds: list, loss_cfg: dict, opt: dict,
             "params": jax.tree_util.tree_unflatten(treedef, leaves)}
 
 
-def change_norms(cfg: dict, seed: int, params, served: bool = False) -> dict:
+def change_norms(cfg: dict, arch, seed: int, params,
+                 served: bool = False) -> dict:
     """Each leaf's norm of ``params`` (float32, or host arrays) less the
     seed's initial weights, one leaf on the device at a time.  ``served``
     first rounds each leaf to the type the seed's weights are served in
     (bfloat16 matrices, float32 norm scales)."""
-    dims = Dims.from_config(cfg)
-    init = W.per_layer_leaves(W.make(dims, bool(cfg["tie_word_embeddings"]),
-                                     seed))
+    init = W.per_layer_leaves(W.make(arch, arch.dims(cfg),
+                                     bool(cfg["tie_word_embeddings"]), seed))
     now = W.per_layer_leaves(params)
     diff = jax.jit(lambda a, b: jnp.linalg.norm(
         (a.astype(jnp.float32) - b.astype(jnp.float32)).ravel()))

@@ -11,6 +11,10 @@ Matrices are truncated normals (+-2 sigma) at 1/sqrt(fan_in) in bfloat16,
 the type they are served in; norm scales are 1 + 0.1 N(0, 1) in float32,
 as the program keeps them.  A tied table is also the output head and
 starts at 1/sqrt(d_model); an untied input table at 1.
+
+Which leaves there are, and their shapes and scales, is the architecture
+module's (``bench/arch/<name>.py``: ``layer_specs(dims)`` for one block,
+``top_specs(dims, tied)`` for the rest); this module keys and draws them.
 """
 from __future__ import annotations
 
@@ -21,36 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchlib.counts import Dims
 from benchlib.traffic import seed_words
-
-
-def _layer_specs(d: Dims):
-    """(path, shape, kind, scale) of one transformer block."""
-    hd = d.head_dim
-    return [
-        (("ln1", "scale"), (d.d_model,), "norm", None),
-        (("ln2", "scale"), (d.d_model,), "norm", None),
-        (("attn", "wq"), (d.d_model, d.heads * hd), "mat", d.d_model ** -0.5),
-        (("attn", "wk"), (d.d_model, d.kv_heads * hd), "mat", d.d_model ** -0.5),
-        (("attn", "wv"), (d.d_model, d.kv_heads * hd), "mat", d.d_model ** -0.5),
-        (("attn", "wo"), (d.heads * hd, d.d_model), "mat", (d.heads * hd) ** -0.5),
-        (("attn", "q_norm"), (hd,), "norm", None),
-        (("attn", "k_norm"), (hd,), "norm", None),
-        (("mlp", "wi_gate"), (d.d_model, d.d_ff), "mat", d.d_model ** -0.5),
-        (("mlp", "wi_up"), (d.d_model, d.d_ff), "mat", d.d_model ** -0.5),
-        (("mlp", "wo"), (d.d_ff, d.d_model), "mat", d.d_ff ** -0.5),
-    ]
-
-
-def _top_specs(d: Dims, tied: bool):
-    specs = [(("embed",), (d.vocab, d.d_model), "mat",
-              d.d_model ** -0.5 if tied else 1.0),
-             (("final_norm", "scale"), (d.d_model,), "norm", None)]
-    if not tied:
-        specs.append((("lm_head",), (d.d_model, d.vocab), "mat",
-                      d.d_model ** -0.5))
-    return specs
 
 
 def _key(words, path, layer=None):
@@ -82,12 +57,12 @@ def _words(seed: int, device=None):
 
 
 @functools.lru_cache(maxsize=None)
-def _make_fn(dims: Dims, tied: bool, device):
+def _make_fn(arch, dims, tied: bool, device):
     def build(words):
         tree: dict = {}
-        for path, shape, kind, scale in _top_specs(dims, tied):
+        for path, shape, kind, scale in arch.top_specs(dims, tied):
             _set(tree, path, _draw(_key(words, path), shape, kind, scale))
-        for path, shape, kind, scale in _layer_specs(dims):
+        for path, shape, kind, scale in arch.layer_specs(dims):
             layers = [_draw(_key(words, ("blocks",) + path, i), shape, kind,
                             scale) for i in range(dims.layers)]
             _set(tree, ("blocks",) + path, jnp.stack(layers))
@@ -97,41 +72,41 @@ def _make_fn(dims: Dims, tied: bool, device):
     return jax.jit(build, out_shardings=shard)
 
 
-def make(dims: Dims, tied: bool, seed: int, device=None):
+def make(arch, dims, tied: bool, seed: int, device=None):
     """The whole tree, made on ``device`` in one jitted call."""
-    return _make_fn(dims, tied, device)(_words(seed, device))
+    return _make_fn(arch, dims, tied, device)(_words(seed, device))
 
 
 @functools.lru_cache(maxsize=None)
-def _layer_fn(dims: Dims):
+def _layer_fn(arch, dims):
     def build(words, i):
         tree: dict = {}
-        for path, shape, kind, scale in _layer_specs(dims):
+        for path, shape, kind, scale in arch.layer_specs(dims):
             w = _draw(_key(words, ("blocks",) + path, i), shape, kind, scale)
             _set(tree, path, w.astype(jnp.float32))
         return tree
     return jax.jit(build)
 
 
-def layer(dims: Dims, seed: int, i: int) -> dict:
+def layer(arch, dims, seed: int, i: int) -> dict:
     """Block ``i`` alone, upcast exactly to float32."""
-    return _layer_fn(dims)(_words(seed), np.uint32(i))
+    return _layer_fn(arch, dims)(_words(seed), np.uint32(i))
 
 
 @functools.lru_cache(maxsize=None)
-def _top_fn(dims: Dims, tied: bool):
+def _top_fn(arch, dims, tied: bool):
     def build(words):
         tree: dict = {}
-        for path, shape, kind, scale in _top_specs(dims, tied):
+        for path, shape, kind, scale in arch.top_specs(dims, tied):
             _set(tree, path, _draw(_key(words, path), shape, kind, scale)
                  .astype(jnp.float32))
         return tree
     return jax.jit(build)
 
 
-def top(dims: Dims, tied: bool, seed: int) -> dict:
+def top(arch, dims, tied: bool, seed: int) -> dict:
     """Embedding, final norm and (untied) head, upcast to float32."""
-    return _top_fn(dims, tied)(_words(seed))
+    return _top_fn(arch, dims, tied)(_words(seed))
 
 
 def per_layer_leaves(tree) -> dict:

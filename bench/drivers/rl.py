@@ -27,11 +27,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchlib import check, counts, reference, traffic
+from benchlib import check, reference, traffic
 from benchlib import weights as W
 from benchlib.harness import (Outcome, live_bytes, memory_peak, now,
                               start_trace)
-from benchlib.model import CompileClock, KernelLengths, Spans, model_config
+from benchlib.model import CompileClock, KernelLengths, Spans
 from repro.algos import LossConfig
 from repro.core.async_controller import AsyncController
 from repro.core.sample_buffer import SampleBuffer
@@ -119,13 +119,14 @@ def _serve(ctx):
     """Build, warm and time the program; returns only host data, so its
     device state is gone once this returns."""
     mix, c = ctx.mix, ctx.config
-    dims = counts.Dims.from_config(c)
-    mcfg = model_config(c)
+    dims = ctx.arch.dims(c)
+    mcfg = ctx.arch.program_config(c)
     spans = Spans()
     g, batch, replicas = mix["group_size"], mix["batch"], mix["replicas"]
     loss_cfg, opt = mix["loss"], mix["optimizer"]
 
-    params = W.make(dims, mcfg.tie_embeddings, ctx.seed, ctx.devices[0])
+    params = W.make(ctx.arch, dims, mcfg.tie_embeddings, ctx.seed,
+                    ctx.devices[0])
     api = dataclasses.replace(get_api(mcfg), init=lambda key: params)
     settings = PipelineSettings(
         async_generation_ratio=mix["alpha"], rollout_batch_size=batch,
@@ -293,8 +294,9 @@ def run(ctx) -> Outcome:
     # ---- correctness, once the program's state is freed
     gc.collect()
     live_bytes()
-    out.checks = check.served(ctx.config, ctx.seed, first, ctx.mix["limits"])
+    out.checks = check.served(ctx.config, ctx.arch, ctx.seed, first,
+                              ctx.mix["limits"])
     controls = ("fp8", "half_batch") if ctx.control else ()
-    out.checks.update(check.training(ctx.config, ctx.seed, feeds, progress,
-                                     ctx.mix, controls=controls))
+    out.checks.update(check.training(ctx.config, ctx.arch, ctx.seed, feeds,
+                                     progress, ctx.mix, controls=controls))
     return out

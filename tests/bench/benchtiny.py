@@ -1,6 +1,8 @@
 """A tiny copy of the benchmark for CPU tests: the real ``bench/`` tree
 and ``BENCHMARK.json``, plus a tiny configuration and a tiny mix added as
-new files, exactly as a later change adds a cell."""
+new files, exactly as a later change adds a cell.  The configuration keeps
+qwen3-4b's ``architectures``, so its program configuration, weights,
+reference block and counts come from ``bench/arch/Qwen3ForCausalLM.py``."""
 from __future__ import annotations
 
 import json

@@ -1,5 +1,6 @@
 """The peaks table and the analytic counts, against hand-worked numbers
-for qwen3-4b (Qwen/Qwen3-4B config.json at 4 of 36 layers)."""
+for qwen3-4b (Qwen/Qwen3-4B config.json at 4 of 36 layers), its sizes
+read by its architecture module, ``bench/arch/Qwen3ForCausalLM.py``."""
 import json
 import pathlib
 import sys
@@ -9,13 +10,13 @@ import pytest
 BENCH = pathlib.Path(__file__).resolve().parents[2] / "bench"
 sys.path.insert(0, str(BENCH))
 
-from benchlib import counts, peaks  # noqa: E402
+from benchlib import counts, harness, peaks  # noqa: E402
 
 
 @pytest.fixture(scope="module")
 def dims():
-    return counts.Dims.from_config(
-        json.loads((BENCH / "configs/qwen3-4b.json").read_text()))
+    c = json.loads((BENCH / "configs/qwen3-4b.json").read_text())
+    return harness.load_arch(c).dims(c)
 
 
 def test_v5e_peaks_and_unknown_kind():
