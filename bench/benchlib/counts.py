@@ -18,10 +18,13 @@ class Dims:
     (``bench/arch/<name>.py``) reads them from its configuration file in
     ``dims(c)``; one whose layers multiply through other parameters (a
     mixture of experts: the k active experts and the router) returns an
-    object whose ``layer_matmul_params`` counts those.  The counts below
-    read only ``matmul_params``, ``trunk_params``, ``head_params``,
-    ``attn_flops``, ``heads``, ``kv_heads``, ``head_dim``, ``layers``,
-    ``vocab`` and ``d_model``."""
+    object whose ``layer_matmul_params`` counts those, and one with more
+    than one kind of layer (``weights.LayerKind``) an object whose
+    ``trunk_params``, ``matmul_params`` and ``attn_flops`` sum its kinds:
+    the counts here take no notice of kinds.  The counts below read only
+    ``matmul_params``, ``trunk_params``, ``head_params``, ``attn_flops``,
+    ``heads``, ``kv_heads``, ``head_dim``, ``layers``, ``vocab`` and
+    ``d_model``."""
     d_model: int
     layers: int
     heads: int

@@ -7,12 +7,18 @@ Files, each found by the name ``BENCHMARK.json`` gives it:
 - ``bench/arch/<architectures[0]>.py``: what depends on the configuration's
   architecture, named by its own ``architectures`` key
   (``Qwen3ForCausalLM``): ``program_config(c)``, the program's model
-  configuration; ``dims(c)``, the sizes ``benchlib.counts`` reads;
-  ``layer_specs(dims)`` and ``top_specs(dims, tied)``, the weights'
-  leaves as ``(path, shape, kind, scale)``; and ``block(w, x, dims, i,
-  theta, eps, cast) -> (x, extra_loss)``, layer ``i`` (traced) of the
-  plain reference and its float32 extra loss (a router's; 0.0 where
-  there is none), summed over the layers into the reference's loss;
+  configuration; ``dims(c)``, the sizes ``benchlib.counts`` reads (the
+  object counts every kind of layer itself); ``top_specs(dims, tied)``,
+  the leaves outside the layers as ``(path, shape, kind, scale)``; and
+  its layers, either as one kind, ``layer_specs(dims)`` (one layer's
+  leaves, stacked over every layer under the root ``blocks``) and
+  ``block(w, x, dims, i, theta, eps, cast) -> (x, extra_loss)`` (layer
+  ``i``, its index over all the layers, traced, of the plain reference
+  and its float32 extra loss: a router's, 0.0 where there is none, summed
+  over the layers into the reference's loss), or as several,
+  ``layer_kinds(dims)``: a list in layer order of
+  ``benchlib.weights.LayerKind(root, layers, specs, block)``, each kind's
+  leaves stacked under a root of its own;
 - ``bench/traffic/<traffic>.json``: the mix's parameters, including the
   driver kind that serves it;
 - ``bench/drivers/<driver>.py``: ``run(ctx) -> Outcome``;

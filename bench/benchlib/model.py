@@ -1,5 +1,5 @@
-"""The span and clock helpers the drivers share, around the program's
-objects.  The program's model configuration comes from the
+"""The span, counter and clock helpers the drivers share, around the
+program's objects.  The program's model configuration comes from the
 configuration's architecture module (``bench/arch/<name>.py``,
 ``program_config``)."""
 from __future__ import annotations
@@ -7,9 +7,11 @@ from __future__ import annotations
 import contextlib
 import threading
 import time
-from typing import Callable, Dict, List
+from typing import Callable, Dict, List, Optional
 
 import jax
+
+from benchlib import program_trace
 
 
 class Spans:
@@ -88,3 +90,49 @@ class KernelLengths:
             self.calls.append((time.monotonic(), [int(x) for x in lengths],
                                chunk))
         return self._step(*args)
+
+
+def counters(objs) -> Dict[str, float]:
+    """The program's counters, summed over ``objs``: every numeric instance
+    attribute whose name starts with ``total_`` (a paged engine's
+    ``total_decode_steps``, ``total_tokens_decoded``, ...).  Host integers
+    only: nothing runs on the device, and no property is called."""
+    out: Dict[str, float] = {}
+    for obj in objs:
+        for name, v in dict(vars(obj)).items():
+            if (name.startswith("total_") and isinstance(v, (int, float))
+                    and not isinstance(v, bool)):
+                out[name] = out.get(name, 0) + v
+    return out
+
+
+def delta(before: Dict[str, float], after: Dict[str, float]) -> Dict[str, float]:
+    """Each counter's growth from ``before`` to ``after``."""
+    return {name: v - before.get(name, 0) for name, v in after.items()}
+
+
+class ProgramSpans:
+    """The program's own spans (``repro.core.tracing``, ``roll.*``) over a
+    traced window.  ``start`` turns them on and resets their totals, so it
+    comes after set-up, just before the profiler starts; ``stop`` reads the
+    totals and turns the spans off, before the profiler stops;
+    ``record(path)`` gives the run's record ``program_spans`` (``{name:
+    (count, seconds)}``) and ``program_idle`` (the device idle of the
+    trace at ``path`` by innermost program span)."""
+
+    def __init__(self, tracing):
+        self.tracing = tracing
+        self.totals: Optional[dict] = None
+
+    def start(self) -> None:
+        self.tracing.enable()
+
+    def stop(self) -> None:
+        try:
+            self.totals = self.tracing.totals()
+        finally:
+            self.tracing.disable()
+
+    def record(self, path: str) -> dict:
+        return {"program_spans": self.totals,
+                "program_idle": program_trace.idle_by_span(path)}

@@ -47,8 +47,8 @@ def attribute_idle(gaps: List[trace.Interval],
                    spans: List[Span]) -> Dict[str, float]:
     """Seconds of idle per innermost covering span (``trace.attribute``)."""
     idle: Dict[str, int] = collections.Counter()
-    for g in gaps:
-        idle[trace.attribute(g, spans)] += g[1] - g[0]
+    for g, name in zip(gaps, trace.attribute(gaps, spans), strict=True):
+        idle[name] += g[1] - g[0]
     return {k: v * 1e-9 for k, v in idle.items()}
 
 

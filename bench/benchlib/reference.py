@@ -3,9 +3,10 @@ matmul precision, and the GRPO train step it is trained with.
 
 What is shared lives here: the embedding, the final norm and the head,
 the layer loop, the loss, AdamW, and the pieces blocks are built from
-(``mm``, ``rmsnorm``, ``rope``, ``attention``).  The block itself is the
-architecture module's (``bench/arch/<name>.py``, ``block``), which every
-function here that runs the model takes as ``arch``.
+(``mm``, ``rmsnorm``, ``rope``, ``attention``).  The blocks themselves are
+the architecture module's (``bench/arch/<name>.py``: each kind of layer's
+``block``, ``weights.layer_kinds``), which every function here that runs
+the model takes as ``arch``.
 
 It imports nothing of the program and reads no weight the program holds:
 weights come from ``benchlib.weights`` and the seed.  ``cast`` (optional)
@@ -119,12 +120,15 @@ def served_logprobs(cfg: dict, arch, seed: int, seqs, *, rows: int,
     top = W.top(arch, dims, tied, seed)
     with jax.default_matmul_precision("highest"):
         xs = [jnp.take(top["embed"], jnp.asarray(c), axis=0) for c in toks]
-        step = jax.jit(functools.partial(arch.block, dims=dims, theta=theta,
-                                         eps=eps, cast=cast))
-        for i in range(dims.layers):
-            w = W.layer(arch, dims, seed, i)
-            xs = [step(w, x, i=np.int32(i))[0] for x in xs]
-            del w
+        i = 0
+        for kind in W.layer_kinds(arch, dims):
+            step = jax.jit(functools.partial(kind.block, dims=dims,
+                                             theta=theta, eps=eps, cast=cast))
+            for _ in range(kind.layers):
+                w = W.layer(arch, dims, seed, i)
+                xs = [step(w, x, i=np.int32(i))[0] for x in xs]
+                del w
+                i += 1
         head = head_matrix(top, tied)
         lp_fn = jax.jit(functools.partial(token_logprobs, cast=cast))
         out = [np.asarray(lp_fn(rmsnorm(x, top["final_norm"]["scale"], eps),
@@ -139,21 +143,26 @@ def served_logprobs(cfg: dict, arch, seed: int, seqs, *, rows: int,
 # --------------------------------------------------------------------------
 
 def forward_logprobs(params, tokens, cfg: dict, arch, cast: Cast = None):
-    """(token logprobs, the sum over the layers of each block's extra loss)."""
+    """(token logprobs, the sum over the layers of each block's extra loss):
+    each kind's stack scanned in layer order with that kind's block."""
     dims = arch.dims(cfg)
     tied = bool(cfg["tie_word_embeddings"])
     theta, eps = float(cfg["rope_theta"]), float(cfg["rms_norm_eps"])
     x = jnp.take(params["embed"], tokens, axis=0)
+    extra, first = [], 0
+    for kind in W.layer_kinds(arch, dims):
+        def body(h, layer, block=kind.block):
+            w, i = layer
+            return block(w, h, dims, i, theta, eps, cast)
 
-    def body(h, layer):
-        w, i = layer
-        return arch.block(w, h, dims, i, theta, eps, cast)
-
-    x, extra = jax.lax.scan(jax.checkpoint(body), x,
-                            (params["blocks"], jnp.arange(dims.layers)))
+        x, e = jax.lax.scan(jax.checkpoint(body), x,
+                            (params[kind.root],
+                             jnp.arange(first, first + kind.layers)))
+        extra.append(e.sum())
+        first += kind.layers
     xf = rmsnorm(x, params["final_norm"]["scale"], eps)
     return (token_logprobs(xf, head_matrix(params, tied), tokens, cast),
-            extra.sum())
+            sum(extra[1:], extra[0]))
 
 
 def grpo_loss(params, batch, cfg: dict, arch, loss_cfg: dict,
@@ -197,6 +206,7 @@ def train_steps(cfg: dict, arch, seed: int, feeds: list, loss_cfg: dict,
     visit the device one leaf at a time, so the device holds only the
     parameters and one gradient."""
     tied = bool(cfg["tie_word_embeddings"])
+    roots = W.roots(arch, arch.dims(cfg))
     params = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32),
                                     W.make(arch, arch.dims(cfg), tied, seed))
     leaves, treedef = jax.tree_util.tree_flatten(params)
@@ -216,7 +226,8 @@ def train_steps(cfg: dict, arch, seed: int, feeds: list, loss_cfg: dict,
             gnorm = float(jnp.sqrt(_sq_norm(grads)))
             scale = min(1.0, opt["grad_clip"] / (gnorm + 1e-9))
             if k == 1:
-                grad_norms = {n: x * scale for n, x in leaf_norms(grads).items()}
+                grad_norms = {n: x * scale
+                              for n, x in leaf_norms(grads, roots).items()}
             grads = jax.tree_util.tree_leaves(grads)
             lr = opt["learning_rate"] * min(1.0, k / opt["warmup_steps"])
             for i in range(len(leaves)):
@@ -237,9 +248,12 @@ def change_norms(cfg: dict, arch, seed: int, params,
     seed's initial weights, one leaf on the device at a time.  ``served``
     first rounds each leaf to the type the seed's weights are served in
     (bfloat16 matrices, float32 norm scales)."""
-    init = W.per_layer_leaves(W.make(arch, arch.dims(cfg),
-                                     bool(cfg["tie_word_embeddings"]), seed))
-    now = W.per_layer_leaves(params)
+    dims = arch.dims(cfg)
+    roots = W.roots(arch, dims)
+    init = W.per_layer_leaves(W.make(arch, dims,
+                                     bool(cfg["tie_word_embeddings"]), seed),
+                              roots)
+    now = W.per_layer_leaves(params, roots)
     diff = jax.jit(lambda a, b: jnp.linalg.norm(
         (a.astype(jnp.float32) - b.astype(jnp.float32)).ravel()))
 
@@ -254,9 +268,9 @@ def change_norms(cfg: dict, arch, seed: int, params,
     return {n: leaf(n) for n in init}
 
 
-def leaf_norms(tree) -> dict:
+def leaf_norms(tree, roots) -> dict:
     return {name: float(jnp.linalg.norm(a.astype(jnp.float32).ravel()))
-            for name, a in W.per_layer_leaves(tree).items()}
+            for name, a in W.per_layer_leaves(tree, roots).items()}
 
 
 def build_feed(samples, mix: dict) -> dict:

@@ -13,13 +13,19 @@ as the program keeps them.  A tied table is also the output head and
 starts at 1/sqrt(d_model); an untied input table at 1.
 
 Which leaves there are, and their shapes and scales, is the architecture
-module's (``bench/arch/<name>.py``: ``layer_specs(dims)`` for one block,
-``top_specs(dims, tied)`` for the rest); this module keys and draws them.
+module's (``bench/arch/<name>.py``): ``top_specs(dims, tied)`` for the
+leaves outside the layers, and for the layers either ``layer_kinds(dims)``,
+its kinds of layer in layer order (``LayerKind``), or ``layer_specs(dims)``
+and ``block``, one kind of every layer rooted at ``blocks``.  Each kind's
+leaves are stacked over its layers under its own root; this module keys
+and draws them.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import zlib
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -56,16 +62,55 @@ def _words(seed: int, device=None):
     return jax.device_put(words, device) if device is not None else words
 
 
+DEFAULT_ROOT = "blocks"     # the root of a module's one kind of layer
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerKind:
+    """One kind of layer: ``layers`` consecutive layers whose leaves
+    (``specs``, one layer's ``(path, shape, kind, scale)``) are stacked
+    under the tree's key ``root``, and ``block(w, x, dims, i, theta, eps,
+    cast) -> (x, extra_loss)``, the plain reference's layer ``i`` (its
+    index over all the layers, traced)."""
+    root: str
+    layers: int
+    specs: tuple
+    block: Callable
+
+
+def layer_kinds(arch, dims) -> list:
+    """The architecture's kinds of layer, in layer order: its
+    ``layer_kinds(dims)``, or one kind of every layer rooted at
+    ``blocks`` from its ``layer_specs`` and ``block``."""
+    if not hasattr(arch, "layer_kinds"):
+        return [LayerKind(DEFAULT_ROOT, dims.layers,
+                          tuple(arch.layer_specs(dims)), arch.block)]
+    kinds = list(arch.layer_kinds(dims))
+    if sum(k.layers for k in kinds) != dims.layers:
+        raise ValueError(f"layer kinds of {[k.layers for k in kinds]} layers, "
+                         f"the configuration has {dims.layers}")
+    if len({k.root for k in kinds}) != len(kinds):
+        raise ValueError(f"two layer kinds share a root: {[k.root for k in kinds]}")
+    return kinds
+
+
+def roots(arch, dims) -> tuple:
+    """The roots of the architecture's stacks of layers."""
+    return tuple(k.root for k in layer_kinds(arch, dims))
+
+
 @functools.lru_cache(maxsize=None)
 def _make_fn(arch, dims, tied: bool, device):
     def build(words):
         tree: dict = {}
         for path, shape, kind, scale in arch.top_specs(dims, tied):
             _set(tree, path, _draw(_key(words, path), shape, kind, scale))
-        for path, shape, kind, scale in arch.layer_specs(dims):
-            layers = [_draw(_key(words, ("blocks",) + path, i), shape, kind,
-                            scale) for i in range(dims.layers)]
-            _set(tree, ("blocks",) + path, jnp.stack(layers))
+        for k in layer_kinds(arch, dims):
+            for path, shape, kind, scale in k.specs:
+                path = (k.root,) + path
+                _set(tree, path, jnp.stack([_draw(_key(words, path, j), shape,
+                                                  kind, scale)
+                                            for j in range(k.layers)]))
         return tree
 
     shard = None if device is None else jax.sharding.SingleDeviceSharding(device)
@@ -78,19 +123,28 @@ def make(arch, dims, tied: bool, seed: int, device=None):
 
 
 @functools.lru_cache(maxsize=None)
-def _layer_fn(arch, dims):
-    def build(words, i):
+def _layer_fn(arch, dims, root: str):
+    k, = (k for k in layer_kinds(arch, dims) if k.root == root)
+
+    def build(words, j):
         tree: dict = {}
-        for path, shape, kind, scale in arch.layer_specs(dims):
-            w = _draw(_key(words, ("blocks",) + path, i), shape, kind, scale)
+        for path, shape, kind, scale in k.specs:
+            w = _draw(_key(words, (root,) + path, j), shape, kind, scale)
             _set(tree, path, w.astype(jnp.float32))
         return tree
     return jax.jit(build)
 
 
 def layer(arch, dims, seed: int, i: int) -> dict:
-    """Block ``i`` alone, upcast exactly to float32."""
-    return _layer_fn(arch, dims)(_words(seed), np.uint32(i))
+    """Layer ``i`` (over all the layers) alone, upcast exactly to float32:
+    its kind's leaves, keyed by the kind's root, each leaf's path and the
+    layer's index within its stack."""
+    j = i
+    for k in layer_kinds(arch, dims):
+        if j < k.layers:
+            return _layer_fn(arch, dims, k.root)(_words(seed), np.uint32(j))
+        j -= k.layers
+    raise IndexError(f"layer {i} of {dims.layers}")
 
 
 @functools.lru_cache(maxsize=None)
@@ -109,13 +163,22 @@ def top(arch, dims, tied: bool, seed: int) -> dict:
     return _top_fn(arch, dims, tied)(_words(seed))
 
 
-def per_layer_leaves(tree) -> dict:
-    """``name -> array`` with every stacked block leaf split per layer
-    (``blocks/attn/wq[2]``): the granularity of the training comparison."""
-    out = {}
+def named_leaves(tree, roots):
+    """``(name, leaf, stacked)`` of every leaf of ``tree``: its keys joined
+    by ``/`` (``blocks/attn/wq``), and whether it lies under one of
+    ``roots``, a stack of layers."""
     for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
         name = "/".join(str(getattr(k, "key", k)) for k in path)
-        if name.startswith("blocks/"):
+        yield name, leaf, name.split("/", 1)[0] in roots
+
+
+def per_layer_leaves(tree, roots) -> dict:
+    """``name -> array`` with every stacked leaf under ``roots`` split per
+    layer (``blocks/attn/wq[2]``, the layer's index within its stack): the
+    granularity of the training comparison."""
+    out = {}
+    for name, leaf, stacked in named_leaves(tree, roots):
+        if stacked:
             for i in range(leaf.shape[0]):
                 out[f"{name}[{i}]"] = leaf[i]
         else:

@@ -15,6 +15,12 @@ sync is applied (copied to the host).
 The same objects then run the window, one ``AsyncController.train`` step
 at a time: it starts at a step boundary and ends at the first step
 boundary ``--seconds`` after it.
+
+What the program counts reaches the metric readers in the record: in every
+run, each counted sample's queue wait and the window's growth of the
+engines' and the trainer's ``total_*`` counters (host integers); in a
+traced run, also the program's ``roll.*`` span totals and the device idle
+by innermost program span (``benchlib.model.ProgramSpans``).
 """
 from __future__ import annotations
 
@@ -31,8 +37,10 @@ from benchlib import check, reference, traffic
 from benchlib import weights as W
 from benchlib.harness import (Outcome, live_bytes, memory_peak, now,
                               start_trace)
-from benchlib.model import CompileClock, KernelLengths, Spans
+from benchlib.model import (CompileClock, KernelLengths, ProgramSpans, Spans,
+                            counters, delta)
 from repro.algos import LossConfig
+from repro.core import tracing
 from repro.core.async_controller import AsyncController
 from repro.core.sample_buffer import SampleBuffer
 from repro.core.scheduler import RolloutProducer
@@ -63,8 +71,8 @@ class Recorder:
     the optimizer state the comparison reads, and each optimizer step's
     real sequence lengths (for the model-FLOP counts)."""
 
-    def __init__(self, trainer, warm: int, b1: float):
-        self.warm, self.b1 = warm, b1
+    def __init__(self, trainer, warm: int, b1: float, roots: tuple):
+        self.warm, self.b1, self.roots = warm, b1, roots
         self.batches = []          # the first ``warm`` batches of Samples
         self.opt_steps = 0
         self.first_grad = None     # leaf -> norm, from m after step 1
@@ -88,7 +96,8 @@ class Recorder:
         self.train_lengths.append((now(), lengths))
         if self.opt_steps == 1:
             self.first_grad = {n: float(v) / (1 - self.b1) for n, v in
-                               leaf_norms(state["opt"]["m"]).items()}
+                               leaf_norms(state["opt"]["m"],
+                                          self.roots).items()}
         if self.opt_steps == self.warm:
             self.master = jax.device_get(state["opt"]["master"])
         return state, m
@@ -101,13 +110,13 @@ def _norms(tree):
         if a.ndim > 1 and a.shape[0] > 0 else jnp.linalg.norm(a), tree)
 
 
-def leaf_norms(tree) -> dict:
-    """Per-layer leaf norms on the device; only the norms come back."""
+def leaf_norms(tree, roots) -> dict:
+    """Per-layer leaf norms on the device, named as
+    ``weights.per_layer_leaves`` names the leaves (every stack under
+    ``roots`` split per layer); only the norms come back."""
     out = {}
-    norms = jax.device_get(_norms(tree))
-    for path, v in jax.tree_util.tree_flatten_with_path(norms)[0]:
-        name = "/".join(str(getattr(k, "key", k)) for k in path)
-        if name.startswith("blocks/"):
+    for name, v, stacked in W.named_leaves(jax.device_get(_norms(tree)), roots):
+        if stacked:
             for i, x in enumerate(np.atleast_1d(v)):
                 out[f"{name}[{i}]"] = float(x)
         else:
@@ -168,7 +177,8 @@ def _serve(ctx):
     built_s = now() - ctx.t_start
 
     # ---- the benchmark's hooks
-    rec = Recorder(trainer, mix["warm_steps"], opt["b1"])
+    rec = Recorder(trainer, mix["warm_steps"], opt["b1"],
+                   W.roots(ctx.arch, dims))
     lengths = []
     for e in engines:
         e.step = spans.wrap("bench.engine_step", e.step)
@@ -189,7 +199,8 @@ def _serve(ctx):
     def on_publish(result):
         failed = result.timed_out or isinstance(result, Rejected)
         if not result.aborted or failed:
-            done[result.task.task_id] = (now(), failed)
+            done[result.task.task_id] = (now(), failed,
+                                         result.task.meta.get("queue_wait_s"))
         publish(result)
 
     producer._submit, producer._publish = on_submit, on_publish
@@ -213,16 +224,17 @@ def _serve(ctx):
         setup_s = now() - ctx.t_start
 
         # ---- the window
+        program = ProgramSpans(tracing)
         if ctx.trace:
             for k in lengths:
                 k.armed = True
+            program.start()
             start_trace(ctx.scratch)
         step_ends = []
         with CompileClock() as clock:
             with spans.span("bench.window"):
                 t0 = now()
-                at_t0 = [(e.total_decode_steps, e.total_tokens_decoded)
-                         for e in engines]
+                at_t0 = counters(engines), counters([trainer])
                 while True:
                     with spans.span("bench.controller_step"):
                         controller.train(1, timeout=timeout)
@@ -230,16 +242,14 @@ def _serve(ctx):
                     if step_ends[-1] - t0 >= ctx.seconds:
                         break
                 t1 = step_ends[-1]
+                at_t1 = counters(engines), counters([trainer])
         if ctx.trace:
+            program.stop()
             jax.profiler.stop_trace()
             for k in lengths:
                 k.armed = False
         window_stats = controller.stats[len(warm_stats):]
         peak = memory_peak(ctx.devices)
-        decode_steps = sum(e.total_decode_steps - a for e, (a, _) in
-                           zip(engines, at_t0, strict=True))
-        tokens_decoded = sum(e.total_tokens_decoded - b for e, (_, b) in
-                             zip(engines, at_t0, strict=True))
     finally:
         producer.stop()
         buffer.close()
@@ -252,17 +262,26 @@ def _serve(ctx):
 
     # ---- end-to-end numbers, from the host clock
     n_steps = len(window_stats)
-    lat = [(d - submitted[tid]) if not failed else math.inf
-           for tid, (d, failed) in done.items()
-           if t0 <= d <= t1 and tid in submitted]
+    counted = [(d - submitted[tid], failed, wait)
+               for tid, (d, failed, wait) in done.items()
+               if t0 <= d <= t1 and tid in submitted]
+    lat = [math.inf if failed else x for x, failed, _ in counted]
+    # a sample never admitted (refused in the queue) has no wait: inf
+    waits = [math.inf if w is None else w for _, _, w in counted]
     n_failed = sum(1 for x in lat if math.isinf(x))
+    engine_totals = delta(at_t0[0], at_t1[0])
     e2e = {"rl_step_s": (t1 - t0) / n_steps, "sample_p90_s": traffic.p90(lat),
            "setup_s": setup_s}
     record = {
         "kind": "rl", "dims": dims, "window": (t0, t1), "steps": [
             dataclasses.asdict(s) for s in window_stats],
         "engine_step_s": spans.within("bench.engine_step", t0, t1),
-        "decode_steps": decode_steps, "tokens_decoded": tokens_decoded,
+        "decode_steps": engine_totals["total_decode_steps"],
+        "tokens_decoded": engine_totals["total_tokens_decoded"],
+        "decode_context": engine_totals.get("total_decode_context"),
+        "engine_totals": engine_totals,
+        "trainer_totals": delta(at_t0[1], at_t1[1]),
+        "queue_waits": waits,
         "kernel_calls": [(tt, ls, ch) for k in lengths for tt, ls, ch in k.calls],
         "train_lengths": [ls for tt, ls in rec.train_lengths if t0 <= tt <= t1],
         "window_compiles": clock.count,
@@ -270,7 +289,9 @@ def _serve(ctx):
     trace = None
     if ctx.trace:
         from benchlib import trace as tr
-        trace = tr.reduce(str(next(ctx.scratch.rglob("*.xplane.pb"))))
+        path = str(next(ctx.scratch.rglob("*.xplane.pb")))
+        trace = tr.reduce(path)
+        record.update(program.record(path))
     print(f"bench: built_s={built_s!r} setup_s={setup_s!r} "
           f"window_s={t1 - t0!r} steps={n_steps} "
           f"samples={len(lat)} compiles_in_window={clock.count} "

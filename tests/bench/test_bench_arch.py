@@ -3,7 +3,9 @@
 Qwen3's weights come out bit-identical to those drawn before its module
 was split out of the harness; and a new architecture needs only new
 files: a test-only one, written into a copy of the benchmark, runs
-``correct`` through the driver with no file of the copy written."""
+``correct`` through the driver with no file of the copy written; and a
+test-only architecture with two kinds of layer, also new files only,
+passes through the weights, the reference and the per-layer naming."""
 import functools
 import hashlib
 import json
@@ -12,6 +14,7 @@ import sys
 import types
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -72,9 +75,9 @@ def block(w, x, dims, i, theta, eps, cast=None):
 '''
 
 
-def _digest(tree, per_layer_leaves) -> str:
+def _digest(tree, per_layer_leaves, roots) -> str:
     h = hashlib.sha256()
-    for name, a in sorted(per_layer_leaves(tree).items()):
+    for name, a in sorted(per_layer_leaves(tree, roots).items()):
         a = np.asarray(a)
         h.update(f"{name}:{a.dtype}:{a.shape}".encode())
         h.update(a.tobytes())
@@ -100,7 +103,8 @@ def test_qwen3_weights_are_those_drawn_before_the_move(tree):
         "layers": lambda: {f"l{i}": W.layer(arch, d, SEED, i)
                            for i in range(d.layers)},
     }[tree]()
-    assert _digest(made, W.per_layer_leaves) == WEIGHT_DIGESTS[tree]
+    assert W.roots(arch, d) == ("blocks",)
+    assert _digest(made, W.per_layer_leaves, W.roots(arch, d)) == WEIGHT_DIGESTS[tree]
 
 
 def test_qwen3_block_adds_no_extra_loss():
@@ -200,3 +204,162 @@ def test_new_architecture_needs_only_new_files(tmp_path, monkeypatch):
     # 3: no file the copy started with was written
     after = _snapshot(root)
     assert {p: v for p, v in after.items() if p in before} == before
+
+
+# two kinds of layer: layer 0 with an MLP of its own width under the root
+# ``dense``, layers 1-3 under ``blocks`` with an extra loss that grows with
+# the layer's index over all the layers
+TWO_KIND_ARCH = '''"""Test-only: a leading layer with an MLP of its own width, then
+Qwen3's blocks, each adding an extra loss scaled by its layer index."""
+import dataclasses
+import pathlib
+
+import jax.numpy as jnp
+
+from benchlib.harness import load_module
+from benchlib.weights import LayerKind
+
+qwen3 = load_module(pathlib.Path(__file__).with_name("Qwen3ForCausalLM.py"),
+                    "bench_arch_qwen3_base")
+dims, top_specs, program_config = qwen3.dims, qwen3.top_specs, qwen3.program_config
+FIRST_FF = 96
+
+
+def later_block(w, x, dims, i, theta, eps, cast=None):
+    y, _ = qwen3.block(w, x, dims, i, theta, eps, cast)
+    return y, 1e-3 * (i + 1) * jnp.mean(y * y)
+
+
+def layer_kinds(d):
+    first = dataclasses.replace(d, d_ff=FIRST_FF)
+    return [LayerKind("dense", 1, tuple(qwen3.layer_specs(first)), qwen3.block),
+            LayerKind("blocks", d.layers - 1, tuple(qwen3.layer_specs(d)),
+                      later_block)]
+'''
+
+# (root, index within the stack) of each layer of the two-kind architecture
+TWO_KIND_LAYERS = [("dense", 0), ("blocks", 0), ("blocks", 1), ("blocks", 2)]
+
+
+@pytest.fixture(scope="module")
+def two_kind(tmp_path_factory):
+    """The two-kind architecture and its configuration, new files in a
+    copy of the benchmark whose every other file is the repository's."""
+    root = benchtiny.make_root(tmp_path_factory.mktemp("twokind"))
+    (root / "bench/arch/TwoKindTestForCausalLM.py").write_text(TWO_KIND_ARCH)
+    cfg = json.loads((root / "bench/configs/tiny.json").read_text())
+    cfg.update(name="tiny-twokind", architectures=["TwoKindTestForCausalLM"],
+               num_hidden_layers=len(TWO_KIND_LAYERS))
+    (root / "bench/configs/tiny-twokind.json").write_text(json.dumps(cfg))
+    for p in (benchtiny.REPO / "bench").rglob("*"):
+        if p.is_file() and "__pycache__" not in p.parts:
+            assert (root / p.relative_to(benchtiny.REPO)).read_bytes() == p.read_bytes()
+    h = benchtiny.harness(root)
+    return cfg, h.load_arch(cfg, root)
+
+
+def test_two_kinds_stack_under_their_roots_and_each_layer_alone_is_its_slice(
+        two_kind):
+    cfg, arch = two_kind
+    from benchlib import weights as W
+    d = arch.dims(cfg)
+    assert W.roots(arch, d) == ("dense", "blocks")
+    tree = W.make(arch, d, True, SEED)
+    assert set(tree) == {"embed", "final_norm", "dense", "blocks"}
+    assert tree["dense"]["mlp"]["wo"].shape == (1, 96, 64)
+    assert tree["blocks"]["mlp"]["wo"].shape == (3, 128, 64)
+    for i, (root, j) in enumerate(TWO_KIND_LAYERS):
+        alone = W.layer(arch, d, SEED, i)
+        sliced = jax.tree_util.tree_map(lambda a: a[j].astype(jnp.float32),
+                                        tree[root])
+        assert jax.tree_util.tree_structure(alone) == \
+            jax.tree_util.tree_structure(sliced)
+        for a, b in zip(jax.tree_util.tree_leaves(alone),
+                        jax.tree_util.tree_leaves(sliced), strict=True):
+            assert np.array_equal(np.asarray(a), np.asarray(b)), (i, root)
+    with pytest.raises(IndexError):
+        W.layer(arch, d, SEED, len(TWO_KIND_LAYERS))
+    # the per-layer names of the training comparison cover both stacks
+    names = set(W.per_layer_leaves(tree, W.roots(arch, d)))
+    assert {"dense/mlp/wo[0]", "blocks/mlp/wo[0]", "blocks/mlp/wo[2]",
+            "embed", "final_norm/scale"} <= names
+    assert not {"dense/mlp/wo[1]", "blocks/mlp/wo[3]", "dense/mlp/wo",
+                "blocks/mlp/wo"} & names
+
+
+def _tokens(d, shape=(2, 12)):
+    return np.asarray(jax.random.randint(jax.random.PRNGKey(3), shape, 0,
+                                         d.vocab), np.int32)
+
+
+def test_two_kinds_forward_is_the_layers_in_order(two_kind):
+    cfg, arch = two_kind
+    from benchlib import reference as R
+    from benchlib import weights as W
+    d = arch.dims(cfg)
+    theta, eps = float(cfg["rope_theta"]), float(cfg["rms_norm_eps"])
+    tokens = _tokens(d)
+    params = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32),
+                                    W.make(arch, d, True, SEED))
+    lp, extra = jax.jit(lambda p, t: R.forward_logprobs(p, t, cfg, arch))(
+        params, tokens)
+    # by hand: each layer alone, through the block its kind names, at its
+    # index over all the layers
+    top = W.top(arch, d, True, SEED)
+    blocks = [arch.qwen3.block] + [arch.later_block] * 3
+    with jax.default_matmul_precision("highest"):
+        x = jnp.take(top["embed"], tokens, axis=0)
+        total = 0.0
+        for i, block in enumerate(blocks):
+            x, e = block(W.layer(arch, d, SEED, i), x, d, np.int32(i), theta, eps)
+            total += float(e)
+        hand = R.token_logprobs(R.rmsnorm(x, top["final_norm"]["scale"], eps),
+                                R.head_matrix(top, True), tokens)
+    np.testing.assert_allclose(np.asarray(lp), np.asarray(hand),
+                               rtol=1e-5, atol=1e-5)
+    assert total > 0 and float(extra) == pytest.approx(total, rel=1e-5)
+    served = R.served_logprobs(cfg, arch, SEED, list(tokens), rows=2)
+    np.testing.assert_allclose(np.stack(served), np.asarray(hand),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_two_kinds_first_gradient_reaches_both_stacks(two_kind):
+    cfg, arch = two_kind
+    from benchlib import reference as R
+    from benchlib import weights as W
+    d = arch.dims(cfg)
+    mix = json.loads((benchtiny.REPO / "bench/traffic/rlvr_grpo8_short.json")
+                     .read_text())
+    tokens = _tokens(d, (4, 12))
+    params = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32),
+                                    W.make(arch, d, True, SEED))
+    lp, _ = R.forward_logprobs(params, tokens, cfg, arch)
+    mask = np.zeros(tokens.shape, np.float32)
+    mask[:, 4:] = 1.0
+    adv = np.asarray([1.0, -1.0, 0.5, -0.5], np.float32)[:, None] * mask
+    feed = {"tokens": tokens, "mask": mask, "advantages": adv,
+            "old_logprobs": np.asarray(lp) * mask}
+    grads = R.train_steps(cfg, arch, SEED, [feed], mix["loss"],
+                          mix["optimizer"])["grad_norm"]
+    stacked = {n: g for n, g in grads.items()
+               if n.startswith(("dense/", "blocks/"))}
+    assert {n.split("/")[0] for n in stacked} == {"dense", "blocks"}
+    assert len(stacked) == 11 * len(TWO_KIND_LAYERS)
+    assert all(g > 0 for g in stacked.values()), stacked
+
+
+def test_driver_leaf_norms_split_every_stack_as_the_reference_does(two_kind):
+    cfg, arch = two_kind
+    from benchlib import reference as R
+    from benchlib import weights as W
+    from benchlib.harness import load_module
+    rl = load_module(benchtiny.REPO / "bench/drivers/rl.py", "bench_driver_rl")
+    d = arch.dims(cfg)
+    tree = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32),
+                                  W.make(arch, d, False, SEED))
+    roots = W.roots(arch, d)
+    prog, ref = rl.leaf_norms(tree, roots), R.leaf_norms(tree, roots)
+    assert set(prog) == set(ref)
+    assert "dense/mlp/wi_up[0]" in prog and "blocks/attn/wq[2]" in prog
+    for n, v in ref.items():
+        assert prog[n] == pytest.approx(v, rel=1e-5), n

@@ -13,6 +13,7 @@ sys.path.insert(0, str(HERE.parents[1] / "bench"))
 
 from benchlib import program_trace as pt  # noqa: E402
 from benchlib import trace  # noqa: E402
+from benchlib.model import ProgramSpans  # noqa: E402
 
 RECORDED = HERE / "data" / "proxy_step_v5e.xplane.pb"
 
@@ -109,3 +110,66 @@ def test_recorded_trace_nests_engine_spans_in_proxy_steps():
     engine = [(s, e) for s, e, n, _ in spans if n.startswith("roll.engine.")]
     assert len(steps) == 8 and len(engine) == 8 * 5
     assert all(any(a <= s and e <= b for a, b in steps) for s, e in engine)
+
+
+class FakeTracing:
+    """``repro.core.tracing``'s calls, recorded, with fixed totals."""
+    TOTALS = {"roll.engine.dispatch": (8, 0.004), "roll.engine.prepare": (8, 0.03),
+              "roll.engine.readback": (8, 0.2), "roll.proxy.step": (8, 0.3)}
+
+    def __init__(self):
+        self.calls = []
+
+    def enable(self):
+        self.calls.append("enable")
+
+    def disable(self):
+        self.calls.append("disable")
+
+    def totals(self):
+        self.calls.append("totals")
+        return dict(self.TOTALS)
+
+
+def test_traced_run_fills_program_spans_and_idle_from_its_trace(recorded):
+    reduced, idle = recorded
+    fake = FakeTracing()
+    program = ProgramSpans(fake)
+    program.start()
+    assert fake.calls == ["enable"]
+    program.stop()
+    assert fake.calls == ["enable", "totals", "disable"]
+    rec = dict(program.record(str(RECORDED)), trace=reduced)
+    assert rec["program_spans"] == FakeTracing.TOTALS
+    assert rec["program_idle"] == idle
+    # the four readers read it
+    assert pt.engine_host_ms(rec) == pytest.approx(1e3 * 0.034 / 8)
+    engine = sum(v for k, v in idle.items()
+                 if k.startswith("roll.engine.") and k != pt.READBACK)
+    proxy = sum(v for k, v in idle.items() if k.startswith("roll.proxy."))
+    assert engine > 0 and proxy > 0
+    assert pt.idle_share_under(rec, "engine") == pytest.approx(
+        100 * engine / reduced["window_s"])
+    assert pt.idle_share_under(rec, "proxy") == pytest.approx(
+        100 * proxy / reduced["window_s"])
+
+
+def test_attribute_idle_agrees_with_the_rule_gap_by_gap():
+    from jax.profiler import ProfileData
+    pd = ProfileData.from_file(str(RECORDED))
+    spans = pt.program_spans(pd)
+    dev, = [p for p in pd.planes if p.name.startswith(trace.DEVICE_PREFIX)]
+    ops = [(int(ev.start_ns), int(ev.end_ns))
+           for ln in dev.lines if ln.name == trace.OPS_LINE for ev in ln.events]
+    gaps = pt.idle_gaps(ops, (min(s for s, _ in ops), max(e for _, e in ops)))
+    by_hand = {}
+    for g in gaps:
+        mid = (g[0] + g[1]) // 2
+        covering = [(e - s, k, n) for k, (s, e, n, _) in enumerate(spans)
+                    if s <= mid < e]
+        name = min(covering)[2] if covering else "host:untraced"
+        by_hand[name] = by_hand.get(name, 0) + (g[1] - g[0])
+    got = pt.attribute_idle(gaps, spans)
+    assert set(got) == set(by_hand) and len(got) > 2
+    for name, ns in by_hand.items():
+        assert got[name] == pytest.approx(ns * 1e-9, rel=1e-12), name

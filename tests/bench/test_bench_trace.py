@@ -2,6 +2,7 @@
 steps of qwen3-4b (4 layers, 16 slots) with a 2 ms host sleep between
 them, each inside a ``bench.engine_step`` / ``bench.host_sleep`` span."""
 import pathlib
+import random
 import sys
 
 import pytest
@@ -28,6 +29,36 @@ def test_union_and_self_times():
     # a while op 0-10 holding a kernel 2-5 and a fusion 6-7
     st = trace.self_times([(0, 10, "while"), (2, 5, "kernel"), (6, 7, "fusion")])
     assert st == {"while": 6, "kernel": 3, "fusion": 1}
+
+
+def _innermost(gap, spans):
+    """The rule ``trace.attribute`` keeps, one gap at a time: the shortest
+    span but the window that covers the gap's midpoint, the first of
+    equal ones."""
+    mid = (gap[0] + gap[1]) // 2
+    best = None
+    for s, e, name, _ in spans:
+        if s <= mid < e and name != "bench.window" and (
+                best is None or e - s < best[1] - best[0]):
+            best = (s, e, name)
+    return best[2] if best else "host:untraced"
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_attribute_is_the_innermost_span_at_each_midpoint(seed):
+    """Nested and overlapping spans on several threads, spans of equal
+    length, midpoints on a span's start and end, gaps no span covers."""
+    rng = random.Random(seed)
+    spans = [(0, 1000, "bench.window", "main")]
+    for k in range(60):
+        s = rng.randrange(0, 900)
+        e = s + rng.choice([0, 5, 10, 10, 40, rng.randrange(1, 300)])
+        spans.append((s, e, f"s{k % 7}", f"t{k % 3}"))
+    gaps = [(a, a + rng.choice([0, 1, 2, 10, 20])) for a in
+            sorted(rng.randrange(-50, 1050) for _ in range(200))]
+    gaps += [(s - 3, s + 3) for s, _, _, _ in spans[1:20]]
+    gaps += [(e - 1, e + 1) for _, e, _, _ in spans[20:40]]
+    assert trace.attribute(gaps, spans) == [_innermost(g, spans) for g in gaps]
 
 
 @pytest.fixture(scope="module")
